@@ -32,6 +32,10 @@ Options (the ``auto[key=value]`` spec grammar):
 ``fallback=<spec>``
     What a table miss dispatches (default ``xla-scan`` — the vectorized
     backend that runs every pattern with no mode prerequisites).
+
+``get_backend("auto", devices=...)`` names the devices: the table is
+read at their count and a multi-rank winner runs over them.  Without
+them, every visible device.
 """
 from __future__ import annotations
 
@@ -57,7 +61,8 @@ class AutoBackend(Backend):
 
     def __init__(self, table: Optional[str] = None,
                  fallback: Optional[str] = None,
-                 timer: str = "synthetic"):
+                 timer: str = "synthetic",
+                 devices: Optional[Sequence] = None):
         from ..bench.tuner import DEFAULT_FALLBACK, load_tuning_table
 
         if fallback is None:
@@ -81,16 +86,20 @@ class AutoBackend(Backend):
                 f"on timer {self.table.timer!r} but auto asked for "
                 f"timer={timer!r}; retune with `benchmarks.run --tune "
                 f"--timer {timer}` or point table= at a matching table")
-        self._ndev: Optional[int] = None
+        self.devices = list(devices) if devices is not None else None
         self._delegates: Dict[str, Backend] = {}
 
+    @classmethod
+    def device_options(cls, devices, options):
+        return {"devices": devices}
+
     # -- resolution (pure lookup, nothing measured) ----------------------
-    def _device_count(self) -> int:
-        if self._ndev is None:
+    def _devices(self) -> List:
+        if self.devices is None:
             import jax
 
-            self._ndev = len(jax.devices())
-        return self._ndev
+            self.devices = jax.devices()
+        return self.devices
 
     def resolve_spec(self, graphs: Sequence[TaskGraph]) -> str:
         """The concrete backend spec this workload dispatches to."""
@@ -99,14 +108,14 @@ class AutoBackend(Backend):
         if self.table is None:
             return self.fallback
         winner = self.table.resolve(
-            graphs_cutout(graphs, ndev=self._device_count()))
+            graphs_cutout(graphs, ndev=len(self._devices())))
         return winner if winner is not None else self.fallback
 
     def delegate(self, graphs: Sequence[TaskGraph]) -> Backend:
         """The (cached) backend instance the workload resolves to."""
         spec = self.resolve_spec(graphs)
         if spec not in self._delegates:
-            self._delegates[spec] = get_backend(spec)
+            self._delegates[spec] = get_backend(spec, devices=self._devices())
         return self._delegates[spec]
 
     # -- execution: pure delegation --------------------------------------

@@ -10,7 +10,7 @@ from __future__ import annotations
 import ast
 import inspect
 import re
-from typing import Callable, Dict, List, Sequence, Tuple, Type
+from typing import Callable, Dict, List, Optional, Sequence, Tuple, Type
 
 import numpy as np
 
@@ -146,18 +146,23 @@ def _check_ctor_kwargs(cls: Type["Backend"], name: str, kwargs: Dict) -> None:
                 f"known options: {known if known else 'none'}")
 
 
-def get_backend(name: str, **kwargs) -> "Backend":
+def get_backend(name: str, devices: Optional[Sequence] = None,
+                **kwargs) -> "Backend":
     """Instantiate a backend from a name or spec string.
 
     Explicit keyword arguments override options embedded in the spec
     string: ``get_backend("shardmap-csp[comm=a2a]", comm="halo")`` builds
-    a halo-mode backend.
+    a halo-mode backend.  ``devices`` places a multi-rank spec's ranks
+    (``Backend.device_options``); a single-device spec runs on JAX's
+    default device whatever it holds.
     """
     base, spec_kw = parse_backend_spec(name)
     if base not in _BACKENDS:
         raise KeyError(f"unknown backend {base!r}; known: {backend_names()}")
     cls = _BACKENDS[base]
     merged = {**spec_kw, **kwargs}
+    if devices is not None:
+        merged = {**cls.device_options(list(devices), merged), **merged}
     _check_ctor_kwargs(cls, base, merged)
     return cls(**merged)
 
@@ -190,6 +195,13 @@ class Backend:
     # never by instantiation, so the default synthetic configuration
     # stays backend-free.
     dispatch_model = "per-task"
+
+    @classmethod
+    def device_options(cls, devices: List, options: Dict) -> Dict:
+        """Constructor options that run the spec ``options`` over
+        ``devices`` (``get_backend(..., devices=)``): none here, the
+        backend runs on one device."""
+        return {}
 
     def prepare(self, graphs: Sequence[TaskGraph]) -> Callable[[], List[np.ndarray]]:
         """Compile/stage the workload; returned callable blocks on finish."""

@@ -48,6 +48,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..compat import pcast, shard_map
 from ..core.graph import TaskGraph
 from ..dist import collectives as CC
+from ..launch.mesh import make_mesh, rank_mesh
 from . import body
 from .base import Backend, register_backend
 
@@ -58,7 +59,8 @@ class PlannedSPMDBackend(Backend):
     """Columns blocked over one mesh axis; movement per a ``CommPlan``.
 
     Ragged widths are handled by the plan's dead-column padding, so any
-    graph width runs on any rank count (including width < ndev).
+    graph width runs on any rank count (including width < ndev).  The
+    ranks are ``mesh``'s devices; without one, see ``launch.mesh.rank_mesh``.
     """
 
     axis = AXIS
@@ -67,14 +69,18 @@ class PlannedSPMDBackend(Backend):
     def __init__(self, mesh: Mesh | None = None, comm: str = "auto",
                  comm_overlap: bool = False):
         if mesh is None:
-            devs = np.array(jax.devices())
-            mesh = Mesh(devs, (self.axis,))
+            mesh = rank_mesh(self.axis)
         if comm not in CC.MODES:
             raise ValueError(f"unknown comm mode {comm!r}; known: {CC.MODES}")
         self.mesh = mesh
         self.comm = comm
         self.comm_overlap = bool(comm_overlap)
         self.ndev = mesh.shape[self.axis]
+
+    @classmethod
+    def device_options(cls, devices, options):
+        """One rank per device: a 1-D mesh of ``devices`` on the rank axis."""
+        return {"mesh": make_mesh((len(devices),), (cls.axis,), devices)}
 
     def plan(self, graph: TaskGraph) -> CC.CommPlan:
         return CC.plan_comm(graph, self.ndev, self.axis, comm=self.comm,
@@ -179,9 +185,6 @@ class PlannedSPMDBackend(Backend):
             mesh=self.mesh,
             in_specs=(P(None, self.axis, None), P(None, self.axis)),
             out_specs=P(self.axis, None),
-            # dynamic mode lowers the kernel loop to `while` (traced trip
-            # count), which the legacy check_rep pass cannot type
-            check_vma=not dynamic,
         )
         fn = jax.jit(shmapped)
         compiled = fn.lower(lmats_j, iters_j).compile()
@@ -316,7 +319,6 @@ class PlannedSPMDBackend(Backend):
             in_specs=(tuple(P(None, self.axis, None) for _ in plans),
                       tuple(P(None, self.axis) for _ in plans)),
             out_specs=tuple(P(self.axis, None) for _ in plans),
-            check_vma=not any(dynamics),
         )
         compiled = jax.jit(shmapped).lower(lmats, iters).compile()
         return compiled, plans, lmats, iters

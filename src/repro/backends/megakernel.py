@@ -33,9 +33,12 @@ CPU CI runs the kernel in Pallas interpret mode (``interpret=None``
 auto-detects the platform); on TPU hosts Mosaic compiles the same kernel
 — all in-kernel arithmetic keeps to Mosaic-legal forms (column-vector
 shapes, int32 checksum math with the uint32 wrap-around base checksums
-precomputed host-side via ``TaskGraph.checksum_table``; see
-``kernels/bodies.py``).  The memory / compute_mxu task kernels are
-validated in interpret mode only.
+precomputed host-side via ``TaskGraph.checksum_table``, the memory
+kernel's windows in a VMEM scratch ref; see ``kernels/bodies.py``).
+``tests/test_tpu_compile.py`` compiles every task kernel for a v5e.  The
+dependency tables are whole-array VMEM blocks, so the width that fits
+shrinks with the height: at H=1000, W=56 compiles for v5e and W=64 is
+refused.
 
 ``comm="onesided"`` adds the distributed form of the same idea: one
 *persistent, communicating* kernel per rank.  Columns are blocked over
@@ -72,8 +75,18 @@ from ..core.kernel_ref import mxu_weight
 from ..core.kernel_spec import MXU_DIM, KernelSpec
 from ..dist import collectives as CC
 from ..kernels import bodies
+from ..launch.mesh import make_mesh, rank_mesh
 from . import body
 from .base import StackedProgramBackend, register_backend
+
+
+def _split_refs(kernel: KernelSpec, refs):
+    """``([w], out, [mem], *rest)`` -> ``(mxu_w, out, mem, rest)``."""
+    refs = list(refs)
+    mxu_w = refs.pop(0)[...] if kernel.kind == "compute_mxu" else None
+    out_ref = refs.pop(0)
+    mem_ref = refs.pop(0) if kernel.kind == "memory" else None
+    return mxu_w, out_ref, mem_ref, refs
 
 
 def _fused_kernel(idx_ref, mask_ref, iters_ref, base_ref, *rest,
@@ -86,13 +99,10 @@ def _fused_kernel(idx_ref, mask_ref, iters_ref, base_ref, *rest,
       base:       (G*H, W, 1) int32 — precomputed base checksums
       [w]:        (MXU_DIM, MXU_DIM) f32 — only for the mxu kernel
       out:        (W, P) f32 block at graph g — the payload wave
+      [mem]:      (nwin, W, span) f32 VMEM scratch — only for the memory
+                  kernel
     """
-    if kernel.kind == "compute_mxu":
-        w_ref, out_ref = rest
-        mxu_w = w_ref[...]
-    else:
-        (out_ref,) = rest
-        mxu_w = None
+    mxu_w, out_ref, mem_ref, _ = _split_refs(kernel, rest)
     t = pl.program_id(1)  # trailing grid dim: sequential on TPU
 
     @pl.when(t == 0)
@@ -125,10 +135,11 @@ def _fused_kernel(idx_ref, mask_ref, iters_ref, base_ref, *rest,
     iters = iters_ref[row]  # (W, 1)
     seed = acc.astype(jnp.float32) * jnp.float32(bodies.FOLD_BLOCK)
     res = bodies.run_kernel_columns(kernel, iters, seed, max_iters,
-                                    mxu_w=mxu_w)  # (W, 1)
+                                    mxu_w=mxu_w, mem_ref=mem_ref)  # (W, 1)
 
     tcol = jnp.zeros((width, 1), jnp.float32) + t.astype(jnp.float32)
-    cols = jax.lax.broadcasted_iota(jnp.float32, (width, 1), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (width, 1), 0
+                                    ).astype(jnp.float32)
     wave = jnp.concatenate(
         [tcol, cols, base.astype(jnp.float32),
          combined.astype(jnp.float32), res], axis=1)
@@ -157,15 +168,13 @@ def _onesided_kernel(rank_ref, idx_ref, mask_ref, iters_ref, base_ref,
       sel:        (n_off, cap, local) f32 one-hot — which of this rank's
                   payload rows each put slot carries
       out:        (local, P) f32 — the rank's payload wave
-      stage/rbuf: (H, n_off * cap, P) f32 scratch — send staging, inbox
+      mem:        (nwin, local, span) f32 scratch — memory kernel only
+      stage/rbuf: (H, n_off * cap, lanes) f32 scratch — send staging,
+                  inbox; a DMA moves whole (8, 128) tiles, so ``cap`` is
+                  a multiple of 8 and the payload is padded to ``lanes``
       send/recv_sem: (H, n_off) DMA semaphores
     """
-    if kernel.kind == "compute_mxu":
-        w_ref, out_ref, *scratch = rest
-        mxu_w = w_ref[...]
-    else:
-        out_ref, *scratch = rest
-        mxu_w = None
+    mxu_w, out_ref, mem_ref, scratch = _split_refs(kernel, rest)
     n_off = len(offsets)
     stage = rbuf = send_sem = recv_sem = None
     if n_off:
@@ -198,9 +207,10 @@ def _onesided_kernel(rank_ref, idx_ref, mask_ref, iters_ref, base_ref,
                 put(t - 1, oi, off).wait_send()
 
     prev_wave = out_ref[...]  # (local, P): t-1 payloads (zeros at t=0)
-    width = prev_wave.shape[0]
+    width, payload_elems = prev_wave.shape
     if n_off:
-        ctx = jnp.concatenate([rbuf[jnp.maximum(t - 1, 0)], prev_wave])
+        inbox = rbuf[jnp.maximum(t - 1, 0)][:, :payload_elems]
+        ctx = jnp.concatenate([inbox, prev_wave])
     else:
         ctx = prev_wave
     ctx_w = ctx.shape[0]
@@ -226,7 +236,7 @@ def _onesided_kernel(rank_ref, idx_ref, mask_ref, iters_ref, base_ref,
     iters = iters_ref[t]
     seed = acc.astype(jnp.float32) * jnp.float32(bodies.FOLD_BLOCK)
     res = bodies.run_kernel_columns(kernel, iters, seed, max_iters,
-                                    mxu_w=mxu_w)  # (local, 1)
+                                    mxu_w=mxu_w, mem_ref=mem_ref)  # (local, 1)
 
     tcol = jnp.zeros((width, 1), jnp.float32) + t.astype(jnp.float32)
     cols = (me * width
@@ -235,22 +245,37 @@ def _onesided_kernel(rank_ref, idx_ref, mask_ref, iters_ref, base_ref,
     wave = jnp.concatenate(
         [tcol, cols, base.astype(jnp.float32),
          combined.astype(jnp.float32), res], axis=1)
-    payload_elems = prev_wave.shape[1]
     if payload_elems > 5:
         ballast = jnp.broadcast_to(res, (width, payload_elems - 5))
         wave = jnp.concatenate([wave, ballast], axis=1)
     out_ref[...] = wave
 
     if n_off:
+        lanes = stage.shape[-1]
+        if lanes > payload_elems:
+            wave = jnp.concatenate(
+                [wave, jnp.zeros((width, lanes - payload_elems),
+                                 jnp.float32)], axis=1)
         # the puts: every rank pushes to every active ring offset — the
         # SPMD-uniform one-sided schedule (dead pairs carry masked rows)
         @pl.when(t < height - 1)
         def _put():
             for oi, off in enumerate(offsets):
+                # one-hot row pick; the MXU's default f32 precision would
+                # round the 20-bit checksums, HIGHEST keeps them exact
                 block = jnp.dot(sel_ref[oi], wave,
-                                preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32,
+                                precision=jax.lax.Precision.HIGHEST)
                 stage[t, oi * cap:(oi + 1) * cap] = block
                 put(t, oi, off).start()
+
+
+def _memory_scratch(kernel: KernelSpec, width: int) -> list:
+    """The memory kernel's VMEM window scratch (none for other kinds)."""
+    if kernel.kind != "memory":
+        return []
+    return [pltpu.VMEM(bodies.memory_scratch_shape(kernel, width),
+                       jnp.float32)]
 
 
 @register_backend("pallas-fused")
@@ -259,21 +284,32 @@ class MegakernelBackend(StackedProgramBackend):
 
     paradigm = "persistent fused kernel (single launch per graph batch)"
     dispatch_model = "per-launch"
+    axis = "cols"  # the comm="onesided" rank axis
 
     def __init__(self, interpret: Optional[bool] = None,
-                 comm: Optional[str] = None):
+                 comm: Optional[str] = None, mesh: Optional[Mesh] = None):
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
         if comm not in (None, "onesided"):
             raise ValueError(
                 f"pallas-fused comm must be 'onesided' (or omitted for the "
                 f"single-device fused kernel), got {comm!r}")
+        if mesh is not None and comm != "onesided":
+            raise ValueError("pallas-fused takes mesh= only with "
+                             "comm='onesided'")
         self.interpret = bool(interpret)
         self.comm = comm
         if comm == "onesided":
-            devs = np.array(jax.devices())
-            self.mesh = Mesh(devs, ("cols",))
-            self.ndev = len(devs)
+            self.mesh = mesh if mesh is not None else rank_mesh(self.axis)
+            self.ndev = self.mesh.shape[self.axis]
+
+    @classmethod
+    def device_options(cls, devices, options):
+        """``comm=onesided`` runs one rank per device; the single-device
+        kernel takes no devices."""
+        if options.get("comm") != "onesided":
+            return {}
+        return {"mesh": make_mesh((len(devices),), (cls.axis,), devices)}
 
     # -- table construction ------------------------------------------------
     @staticmethod
@@ -315,6 +351,7 @@ class MegakernelBackend(StackedProgramBackend):
             # block index g, revisited for every t: the payload wave
             out_specs=pl.BlockSpec((W, P), lambda g, t: (g, 0)),
             out_shape=jax.ShapeDtypeStruct((ngraphs * W, P), jnp.float32),
+            scratch_shapes=_memory_scratch(g0.kernel, W),
             interpret=interpret,
         )
 
@@ -331,6 +368,7 @@ class MegakernelBackend(StackedProgramBackend):
         lm = plan.local_mats  # (H, padded, ctx) — plan coords, src-major
         H, padded, _ = lm.shape
         ndev, local, cap = plan.ndev, plan.local, plan.a2a_cap
+        slot = -(-cap // 8) * 8  # inbox rows per put, tile-aligned
         offsets = ([off for off, _, _ in plan._onesided_offsets]
                    if cap else [])
         n_off = len(offsets)
@@ -348,10 +386,10 @@ class MegakernelBackend(StackedProgramBackend):
             ks = []
             for c in np.nonzero(lm[t, i])[0]:
                 if c >= ndev * cap:  # the local block
-                    ks.append(n_off * cap + (c - ndev * cap))
+                    ks.append(n_off * slot + (c - ndev * cap))
                 else:
                     s, k = c // cap, c % cap
-                    ks.append(oi_of[(d - s) % ndev] * cap + k)
+                    ks.append(oi_of[(d - s) % ndev] * slot + k)
             idx[d, t, i - d * local, :len(ks)] = ks
             mask[d, t, i - d * local, :len(ks)] = 1
         base = np.zeros((H, padded), np.int64)
@@ -361,7 +399,7 @@ class MegakernelBackend(StackedProgramBackend):
             return np.ascontiguousarray(
                 a.reshape(H, ndev, local, -1).transpose(1, 0, 2, 3))
 
-        sel = np.zeros((ndev, max(n_off, 1), max(cap, 1), local),
+        sel = np.zeros((ndev, max(n_off, 1), max(slot, 1), local),
                        np.float32)
         for oi, (_, idx_tab, _) in enumerate(plan._onesided_offsets
                                              if cap else []):
@@ -376,10 +414,13 @@ class MegakernelBackend(StackedProgramBackend):
         return offsets, tabs
 
     def _onesided_call(self, graph: TaskGraph, plan: CC.CommPlan,
-                       offsets: List[int], radix: int, interpret: bool):
-        """The per-rank single-launch pallas_call (grid over timesteps)."""
+                       offsets: List[int], radix: int, cap: int,
+                       interpret: bool):
+        """The per-rank single-launch pallas_call (grid over timesteps);
+        ``cap`` is the tile-aligned inbox rows per put."""
         H, local, Pels = graph.height, plan.local, graph.payload_elems
-        cap, n_off = plan.a2a_cap, len(offsets)
+        n_off = len(offsets)
+        lanes = -(-Pels // 128) * 128
         whole = lambda shape: pl.BlockSpec(
             shape, lambda t: (0,) * len(shape))
         in_specs = [
@@ -394,11 +435,11 @@ class MegakernelBackend(StackedProgramBackend):
         ]
         if graph.kernel.kind == "compute_mxu":
             in_specs.append(whole((MXU_DIM, MXU_DIM)))
-        scratch = []
+        scratch = _memory_scratch(graph.kernel, local)
         if n_off:
-            scratch = [
-                pltpu.VMEM((H, n_off * cap, Pels), jnp.float32),  # stage
-                pltpu.VMEM((H, n_off * cap, Pels), jnp.float32),  # rbuf
+            scratch += [
+                pltpu.VMEM((H, n_off * cap, lanes), jnp.float32),  # stage
+                pltpu.VMEM((H, n_off * cap, lanes), jnp.float32),  # rbuf
                 pltpu.SemaphoreType.DMA((H, n_off)),
                 pltpu.SemaphoreType.DMA((H, n_off)),
             ]
@@ -417,14 +458,15 @@ class MegakernelBackend(StackedProgramBackend):
 
     def _program_onesided(self, graphs: List[TaskGraph], interpret: bool):
         """One persistent communicating kernel per rank per graph."""
-        mesh, ndev = self.mesh, self.ndev
+        mesh, ndev, axis = self.mesh, self.ndev, self.axis
         ranks = jnp.arange(ndev, dtype=jnp.int32).reshape(ndev, 1)
         shards, args = [], []
         for g in graphs:
-            plan = CC.plan_comm(g, ndev, "cols", comm="onesided")
+            plan = CC.plan_comm(g, ndev, axis, comm="onesided")
             offsets, tabs = self._onesided_tables(g, plan)
-            radix = tabs[0].shape[-1]
-            call = self._onesided_call(g, plan, offsets, radix, interpret)
+            radix, cap = tabs[0].shape[-1], tabs[4].shape[2]
+            call = self._onesided_call(g, plan, offsets, radix, cap,
+                                       interpret)
             n_tabs = len(tabs)
 
             def per_rank(rank, *tables, call=call, n_tabs=n_tabs):
@@ -433,12 +475,12 @@ class MegakernelBackend(StackedProgramBackend):
                     sharded.append(tables[5])  # mxu weight, replicated
                 return call(rank, *sharded)
 
-            in_specs = (P("cols", None),) + (P("cols"),) * 5
+            in_specs = (P(axis, None),) + (P(axis),) * 5
             if n_tabs > 5:
                 in_specs += (P(None, None),)
             shards.append((shard_map(
                 per_rank, mesh=mesh, in_specs=in_specs,
-                out_specs=P("cols", None), check_vma=False), plan.width))
+                out_specs=P(axis, None), check_vma=False), plan.width))
             args.append((ranks,) + tuple(jnp.asarray(a) for a in tabs))
 
         def program(all_args):

@@ -111,6 +111,7 @@ def lowered_moe_hlo(spec: MoEDispatchSpec) -> str:
     import jax.numpy as jnp
 
     from ..dist.sharding import make_rules, use_rules
+    from ..launch.mesh import make_mesh
     from ..models import moe as MO
     from ..models.layers import split_leaves
 
@@ -120,7 +121,7 @@ def lowered_moe_hlo(spec: MoEDispatchSpec) -> str:
             f"moe_dispatch spec needs {need} devices "
             f"({spec.data}x{spec.model} mesh), have {len(jax.devices())}")
     cfg = spec.config()
-    mesh = jax.make_mesh((spec.data, spec.model), ("data", "model"))
+    mesh = make_mesh((spec.data, spec.model), ("data", "model"))
     rules = make_rules(mesh)
     params, _ = split_leaves(MO.init_moe(jax.random.PRNGKey(0), cfg))
     x = jnp.zeros((spec.batch, spec.seq, cfg.d_model), jnp.float32)

@@ -11,16 +11,17 @@ as ranks (and therefore communication) grow.  This module is that family:
     ``n`` times wider), and the rank sweep (default ``{1, 2, 4, 8}``).
 
 ``run_scaling``
-    The rank launcher.  JAX fixes its device count at process start, so
-    each rank count is measured in a *relaunched subprocess* with
-    ``JAX_NUM_CPU_DEVICES=n`` (jax >= 0.5) or
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=n`` (0.4.x) —
-    the first in-repo multi-rank launcher; before this only CI set the
-    variable.  The child (``python -m repro.bench.scaling``) runs the
-    ordinary ``run_scenario`` sweep for its rank count and prints one
-    JSON cell; the parent assembles the ``kind="metg_scaling"`` artifact:
-    per-rank elapsed, weak-scaling efficiency ``T(1)/T(n)`` (ideal 1.0 —
-    work per rank is constant), and the per-granularity contour.
+    The rank launcher.  On a TPU every rank count runs in the one process
+    that holds the chips, over a sub-mesh of ``jax.devices()[:n]``: a chip
+    belongs to one process at a time, so a child could not reach it.  On
+    the CPU, JAX fixes its device count at process start, so each rank
+    count is measured in a *relaunched subprocess* with
+    ``JAX_NUM_CPU_DEVICES=n``.  The child (``python -m
+    repro.bench.scaling``) runs the ordinary ``run_scenario`` sweep for its
+    rank count and prints one JSON cell.  Either way the parent assembles
+    the ``kind="metg_scaling"`` artifact: per-rank elapsed, weak-scaling
+    efficiency ``T(1)/T(n)`` (ideal 1.0 — work per rank is constant), and
+    the per-granularity contour.
 
 Determinism: under the ``SyntheticTimer`` the child charges the
 rank-count model (``SyntheticTimer.ranks``, a pure function of
@@ -138,37 +139,21 @@ def scaling_timer(timer: Optional[Timer]) -> Optional[Timer]:
 
 # ------------------------------------------------------ subprocess launch
 
-def _jax_num_cpu_devices_supported() -> bool:
-    """jax >= 0.5 reads ``JAX_NUM_CPU_DEVICES``; 0.4.x needs the XLA
-    flag (and rejects setting both).  Resolved from package metadata so
-    the parent never imports jax just to launch children."""
-    try:
-        from importlib.metadata import version
-
-        major, minor = (int(x) for x in version("jax").split(".")[:2])
-    except Exception:
-        return True
-    return (major, minor) >= (0, 5)
-
-
 def rank_env(nranks: int, base: Optional[Dict[str, str]] = None,
              ) -> Dict[str, str]:
     """The child environment for an ``nranks``-device relaunch.
 
     Strips any inherited device-count forcing first (the CI multi-rank
     step exports ``JAX_NUM_CPU_DEVICES=8``; the child must see *its*
-    rank count, not the parent's), keeps unrelated ``XLA_FLAGS``, and
-    prepends this checkout's ``src`` so ``python -m repro.bench.scaling``
-    resolves the same code the parent runs.
+    rank count, not the parent's, and JAX rejects the XLA flag beside
+    the variable), keeps unrelated ``XLA_FLAGS``, and prepends this
+    checkout's ``src`` so ``python -m repro.bench.scaling`` resolves the
+    same code the parent runs.
     """
     env = dict(os.environ if base is None else base)
-    env.pop("JAX_NUM_CPU_DEVICES", None)
+    env["JAX_NUM_CPU_DEVICES"] = str(nranks)
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if not f.startswith("--xla_force_host_platform_device_count")]
-    if _jax_num_cpu_devices_supported():
-        env["JAX_NUM_CPU_DEVICES"] = str(nranks)
-    else:
-        flags.append(f"--xla_force_host_platform_device_count={nranks}")
     if flags:
         env["XLA_FLAGS"] = " ".join(flags)
     else:
@@ -205,21 +190,26 @@ def _child_timer(payload: Optional[Dict], nranks: int) -> Optional[Timer]:
 
 
 def run_rank_cell(spec: ScalingSpec, nranks: int, smoke: bool,
-                  timer_payload: Optional[Dict]) -> Dict:
-    """Measure one (spec, rank count) cell — the child's whole job."""
+                  timer_payload: Optional[Dict],
+                  devices: Optional[Sequence] = None) -> Dict:
+    """Measure one (spec, rank count) cell — the child's whole job, or,
+    with ``devices`` (the cell's ``nranks`` devices), one in-process cell."""
     timer = _child_timer(timer_payload, nranks)
     sc = spec.scenario_for(nranks, smoke=smoke)
-    result = run_scenario(sc, timer=timer)
-    if timer is None:
+    result = run_scenario(sc, timer=timer,
+                          devices=devices if timer is None else None)
+    if timer_payload is not None:
+        ndevices = nranks  # the synthetic rank model
+    elif devices is not None:
+        ndevices = len(devices)
+    else:
         import jax
 
-        devices = len(jax.devices())
-    else:
-        devices = nranks
+        ndevices = len(jax.devices())
     return {
         "ranks": nranks,
         "width": result.spec.width,
-        "devices": devices,
+        "devices": ndevices,
         "timer": result.timer,
         "timer_config": dict(result.timer_config),
         "sweep": _sweep_doc(result.spec.sweep),
@@ -339,13 +329,30 @@ class ScalingResult:
 
 def run_scaling(spec: ScalingSpec, timer: Optional[Timer] = None,
                 smoke: bool = False,
-                python: str = sys.executable) -> ScalingResult:
-    """Measure one weak-scaling series via per-rank subprocess relaunch."""
+                python: str = sys.executable,
+                devices: Optional[Sequence] = None) -> ScalingResult:
+    """Measure one weak-scaling series.
+
+    With ``devices``, and by default on a TPU (over ``jax.devices()``),
+    every rank count ``n`` runs in this process over ``devices[:n]``;
+    otherwise each runs in a relaunched child pinned to ``n`` CPU devices.
+    """
+    import jax
+
     from .artifact import validate_artifact
 
     payload = _timer_payload(scaling_timer(timer))
-    cells = [_launch_cell(spec, n, smoke, payload, python)
-             for n in spec.ranks]
+    if devices is None and jax.default_backend() == "tpu":
+        devices = jax.devices()
+    if devices is not None:
+        if len(devices) < spec.ranks[-1]:
+            raise ValueError(f"{spec.name}: ranks {spec.ranks} need "
+                             f"{spec.ranks[-1]} devices, have {len(devices)}")
+        cells = [run_rank_cell(spec, n, smoke, payload, devices=devices[:n])
+                 for n in spec.ranks]
+    else:
+        cells = [_launch_cell(spec, n, smoke, payload, python)
+                 for n in spec.ranks]
     timers = {c["timer"] for c in cells}
     if len(timers) != 1:
         raise RuntimeError(

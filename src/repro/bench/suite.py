@@ -329,10 +329,12 @@ def run_suite(suite: Suite, out_dir: str, smoke: bool = False,
               parallel: Optional[int] = None) -> SuiteResult:
     """Execute every cell (and its rollouts) and collect the outcome.
 
-    Cells run as ``benchmarks.run`` subprocesses, at most
-    ``parallel`` (default: the suite's ``parallel``) at a time; a
-    nonzero cell never cancels the others.  Rollout byte-comparison
-    applies only to synthetic-timer cells.
+    Cells run as ``benchmarks.run`` subprocesses.  Synthetic-timer cells
+    run at most ``parallel`` (default: the suite's ``parallel``) at a
+    time; every other cell measures the device, which belongs to one
+    process at a time, so those run afterwards, one by one, with nothing
+    beside them.  A nonzero cell never cancels the others.  Rollout
+    byte-comparison applies only to synthetic-timer cells.
     """
     cwd = cwd or _repo_root()
     env = dict(os.environ)
@@ -350,11 +352,14 @@ def run_suite(suite: Suite, out_dir: str, smoke: bool = False,
 
     nworkers = parallel if parallel is not None else suite.parallel
     result = SuiteResult(suite=suite, out_dir=out_dir)
+    synthetic = [j for j in jobs if suite.cell_timer(j[0]) == "synthetic"]
     with ThreadPoolExecutor(max_workers=max(1, nworkers)) as pool:
         futures = [pool.submit(_run_cell, suite, cell, d, r, smoke,
                                python, cwd, env)
-                   for cell, r, d in jobs]
+                   for cell, r, d in synthetic]
         runs = [f.result() for f in futures]
+    runs += [_run_cell(suite, cell, d, r, smoke, python, cwd, env)
+             for cell, r, d in jobs if suite.cell_timer(cell) != "synthetic"]
 
     order = {(c.family, r): i for i, (c, r, _) in enumerate(jobs)}
     runs.sort(key=lambda cr: order[(cr.cell.family, cr.rollout)])

@@ -8,7 +8,7 @@ and reduces the points to a ``METGResult``.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 from .metg import METGResult, SweepPoint, compute_metg, sweep_point
 from .scenario import ScenarioSpec
@@ -43,18 +43,24 @@ def run_scenario(
     spec: ScenarioSpec,
     timer: Optional[Timer] = None,
     peak_rate: Optional[float] = None,
+    devices: Optional[Sequence] = None,
 ) -> ScenarioResult:
     """Measure one scenario under ``timer`` (default: wall clock).
 
     ``peak_rate`` pins the 100 %-efficiency baseline externally (e.g. the
     balanced peak when measuring an imbalance penalty); by default the
-    sweep self-normalizes against its own best rate.
+    sweep self-normalizes against its own best rate.  ``devices`` are
+    what the default wall clock runs a multi-rank backend over.
     """
     spec = spec.resolved()
     if timer is None:
         timer = WallClockTimer(warmup=spec.sweep.warmup,
                                repeats=spec.sweep.repeats,
-                               percentile=spec.sweep.percentile)
+                               percentile=spec.sweep.percentile,
+                               devices=devices)
+    elif devices is not None:
+        raise ValueError("devices= places the default wall clock's "
+                         "backend; give your timer its own devices")
     points: List[SweepPoint] = []
     for iters in spec.sweep.iteration_schedule():
         graphs = spec.graphs(iters)

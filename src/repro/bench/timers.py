@@ -26,7 +26,8 @@ import dataclasses
 import math
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Protocol, Sequence, Tuple, runtime_checkable
+from typing import (Dict, List, Optional, Protocol, Sequence, Tuple,
+                    runtime_checkable)
 
 from ..core.graph import TaskGraph
 
@@ -57,12 +58,21 @@ def timer_config(timer: Timer) -> Dict[str, object]:
     return {}
 
 
-def cached_backend(cache: Dict[str, object], backend_name: str):
-    """Per-timer backend cache (avoids re-building meshes per sweep point)."""
+def cached_backend(cache: Dict[str, object], backend_name: str,
+                   devices: Optional[Sequence] = None):
+    """Per-timer backend cache (avoids re-building meshes per sweep point).
+
+    A multi-rank backend runs over ``devices``, by default every visible
+    device.
+    """
     if backend_name not in cache:
+        import jax
+
         from ..backends import get_backend
 
-        cache[backend_name] = get_backend(backend_name)
+        cache[backend_name] = get_backend(
+            backend_name,
+            devices=jax.devices() if devices is None else devices)
     return cache[backend_name]
 
 
@@ -121,16 +131,22 @@ def pick_sample(samples: Sequence[float], percentile: float) -> float:
 
 @dataclass
 class WallClockTimer:
-    """Times real backend executions (prepare once, run repeatedly)."""
+    """Times real backend executions (prepare once, run repeatedly).
+
+    ``devices``: what a multi-rank backend runs over (default: every
+    visible device).
+    """
 
     warmup: int = 1
     repeats: int = 3
     percentile: float = 0.0  # 0 => best-of-repeats
+    devices: Optional[Sequence] = field(default=None, repr=False)
     name: str = field(default="wallclock", init=False)
     _backends: Dict[str, object] = field(default_factory=dict, repr=False)
 
     def measure(self, backend_name: str, graphs: Sequence[TaskGraph]) -> float:
-        runner = cached_backend(self._backends, backend_name).prepare_many(graphs)
+        runner = cached_backend(self._backends, backend_name,
+                                self.devices).prepare_many(graphs)
         for _ in range(max(self.warmup, 0)):
             runner()
         samples: List[float] = []

@@ -211,10 +211,13 @@ def backend_mode_specs(name: str) -> List[str]:
     rejects — e.g. ``pallas-fused[comm=auto]`` (the megakernel only
     accepts one-sided or no comm mode) never becomes a candidate.
     """
+    import jax
+
     from ..backends.base import (backend_option_signature,
                                  canonical_backend_spec, get_backend)
 
     sig = backend_option_signature(name)
+    one = jax.devices()[:1]  # a rank backend needs devices to be built
     axes = [k for k in _MODE_SPACE if sig is not None and k in sig]
     specs = {name}
     for combo in itertools.product(*(_MODE_SPACE[k] for k in axes)):
@@ -224,7 +227,7 @@ def backend_mode_specs(name: str) -> List[str]:
         opts = ",".join(f"{k}={v}" for k, v in sorted(kwargs.items()))
         spec = canonical_backend_spec(f"{name}[{opts}]")
         try:
-            get_backend(spec)
+            get_backend(spec, devices=one)
         except (ValueError, KeyError):
             continue  # the constructor vetoed the combo: not legal
         specs.add(spec)

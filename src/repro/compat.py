@@ -1,46 +1,23 @@
-"""JAX version compatibility shims.
+"""The one place ``src/`` reaches ``jax.shard_map`` and ``jax.lax.pcast``.
 
-The repo targets the modern ``jax.shard_map`` API (keyword ``mesh`` /
-``in_specs`` / ``out_specs``, ``check_vma``), but must also run on
-JAX 0.4.x where ``shard_map`` lives in ``jax.experimental.shard_map``
-(with the replication check spelled ``check_rep``) and ``jax.lax.pcast``
-does not exist.  All code under ``src/`` imports these names from here
-instead of touching ``jax.shard_map`` / ``jax.lax.pcast`` directly
-(enforced by ``tests/test_compat.py``).
+All code under ``src/`` imports these names from here instead of
+touching ``jax.shard_map`` / ``jax.lax.pcast`` directly (enforced by
+``tests/test_compat.py``), so a change of either API is one edit.
 """
 from __future__ import annotations
 
 import jax
 
-_native_shard_map = getattr(jax, "shard_map", None)
-if _native_shard_map is None:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-else:
-    _legacy_shard_map = None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, **kwargs):
-    """``jax.shard_map`` on new JAX; the experimental one on 0.4.x.
-
-    ``check_vma`` maps onto the legacy ``check_rep`` flag — both gate the
-    same replication/varying-manual-axes validation.
-    """
-    if _native_shard_map is not None:
-        return _native_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma, **kwargs)
-    return _legacy_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma, **kwargs)
+shard_map = jax.shard_map
 
 
 def pcast(x, axis_names, to="varying"):
-    """``jax.lax.pcast`` where available; identity otherwise.
+    """``jax.lax.pcast`` that accepts values already varying over the axes.
 
-    Old shard_map has no varying-manual-axes typing, so there is nothing
-    to cast — values become device-varying implicitly.
+    ``jax.lax.pcast`` raises on a varying -> varying cast; inside a
+    ``shard_map`` an input sharded over ``axis_names`` already is, and
+    then there is nothing to cast.
     """
-    fn = getattr(jax.lax, "pcast", None)
-    if fn is None:
+    if to == "varying" and set(axis_names) <= jax.typeof(x).vma:
         return x
-    return fn(x, axis_names, to=to)
+    return jax.lax.pcast(x, axis_names, to=to)

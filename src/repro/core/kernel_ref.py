@@ -16,6 +16,8 @@ The TPU adaptation of the paper's kernels (paper Listing 1):
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 from .kernel_spec import COMPUTE_TILE, MXU_DIM, KernelSpec
@@ -38,7 +40,10 @@ def mxu_weight() -> np.ndarray:
     return w
 
 
+@functools.lru_cache(maxsize=4096)
 def run_kernel_ref(kernel: KernelSpec, iterations: int) -> float:
+    """One task's kernel result; memoized, since every task of a graph
+    with the same duration computes the same value (a float: immutable)."""
     if kernel.kind == "empty":
         return 0.0
     if kernel.kind == "compute":

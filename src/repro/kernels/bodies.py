@@ -15,6 +15,9 @@ Mosaic (Pallas TPU) legality constraints honored here:
 * no uint32 arithmetic (checksums are int32-exact: values < 2^20)
 * no captured array constants — the MXU weight is an explicit argument so
   kernels can pass it in as a ref instead of baking in a (128,128) const
+* no ``dynamic_slice`` on a value (Mosaic has no lowering for it) — Pallas
+  callers give the memory kernel a ``(nwin, W, span)`` VMEM ref and it
+  walks the windows by indexing the ref's leading axis
 """
 from __future__ import annotations
 
@@ -83,15 +86,42 @@ def memory_geometry(kernel: KernelSpec) -> Tuple[int, int, int]:
     return span, size, size // span
 
 
+def memory_scratch_shape(kernel: KernelSpec, width: int) -> Tuple[int, int, int]:
+    """The ``(nwin, W, span)`` VMEM scratch a Pallas caller gives the
+    memory kernel (``mem_ref`` of ``run_kernel_columns``)."""
+    span, _, nwin = memory_geometry(kernel)
+    return nwin, width, span
+
+
+def _memory_columns_ref(iters_col, seed_col, max_iters: int, mem_ref):
+    """The memory kernel on a ``(nwin, W, span)`` ref: iteration ``k``
+    rewrites window ``k % nwin`` — the value path's window walk, with the
+    window picked by a leading-axis ref index instead of a dynamic slice."""
+    nwin = mem_ref.shape[0]
+    mem_ref[...] = jnp.broadcast_to((jnp.float32(1.0) + seed_col)[None],
+                                    mem_ref.shape)
+
+    def body(k, carry):
+        j = k % nwin
+        window = mem_ref[j]
+        mem_ref[j] = jnp.where(k < iters_col, memory_step(window), window)
+        return carry
+
+    jax.lax.fori_loop(0, max_iters, body, 0)
+    return mem_ref[0][:, 0:1]
+
+
 def run_kernel_columns(kernel: KernelSpec, iters_col, seed_col,
                        max_iters: int, dynamic: bool = False,
-                       mxu_w: Optional[jax.Array] = None):
+                       mxu_w: Optional[jax.Array] = None, mem_ref=None):
     """The shared task-kernel body in column-vector form.
 
     ``iters_col``/``seed_col`` are ``(W, 1)``; returns ``(W, 1)`` f32
     results.  ``mxu_w`` lets Pallas callers pass the MXU weight as a ref
     value (kernels must not capture array constants); jitted callers leave
-    it None and get the host-side constant.
+    it None and get the host-side constant.  ``mem_ref`` is the memory
+    kernel's scratch for Pallas callers (``memory_scratch_shape``);
+    jitted callers leave it None and walk a value.
     """
     width = seed_col.shape[0]
 
@@ -113,6 +143,9 @@ def run_kernel_columns(kernel: KernelSpec, iters_col, seed_col,
         out = masked_loop(lambda k, bb: mxu_step(bb, w), b, iters_col,
                           max_iters, dynamic)
         return out[:, 0, :][:, 0:1]
+
+    if kernel.kind == "memory" and mem_ref is not None:
+        return _memory_columns_ref(iters_col, seed_col, max_iters, mem_ref)
 
     if kernel.kind == "memory":
         span, size, nwin = memory_geometry(kernel)

@@ -29,7 +29,7 @@ NEG_INF = -1e30
 def _flash_kernel(
     q_ref, k_ref, v_ref,  # (1, 1, bq, d), (1, 1, bk, d), (1, 1, bk, d)
     o_ref,                # (1, 1, bq, d)
-    acc_ref, m_ref, l_ref,  # VMEM scratch: (bq, d) f32, (bq,) f32, (bq,) f32
+    acc_ref, m_ref, l_ref,  # VMEM scratch: (bq, d), (bq, 1), (bq, 1) f32
     *,
     scale: float,
     causal: bool,
@@ -38,6 +38,7 @@ def _flash_kernel(
     block_q: int,
     block_k: int,
     num_k_blocks: int,
+    kv_len: Optional[int],
 ):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
@@ -63,30 +64,34 @@ def _flash_kernel(
         mask &= kpos <= qpos
     if window is not None:
         mask &= kpos > qpos - window
+    if kv_len is not None:  # zero-padded keys past the real length
+        mask &= kpos < kv_len
     s = jnp.where(mask, s, NEG_INF)
 
+    # row statistics stay (bq, 1): a rank-1 (bq,) value needs a shape cast
+    # to broadcast against (bq, bk), which Mosaic cannot lower
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
+    p = jnp.exp(s - m_new)
     # fully-masked rows: m_new stays NEG_INF; exp(NEG_INF - NEG_INF)=1 would
     # pollute l, so zero those rows explicitly.
     row_alive = m_new > NEG_INF / 2
-    p = jnp.where(row_alive[:, None], p, 0.0)
+    p = jnp.where(row_alive, p, 0.0)
     alpha = jnp.where(row_alive, alpha, 0.0)
 
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
     pv = jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
+    acc_ref[...] = acc_ref[...] * alpha + pv
     m_ref[...] = m_new
 
     @pl.when(ik == num_k_blocks - 1)
     def _finalize():
         l = l_ref[...]
         safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0, 0] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -107,8 +112,15 @@ def flash_attention(
     group = Hq // Hkv
     block_q = min(block_q, Sq)
     block_k = min(block_k, Skv)
-    assert Sq % block_q == 0 and Skv % block_k == 0
-    nq, nk = Sq // block_q, Skv // block_k
+    # lengths longer than a block and not a multiple of it are zero-padded
+    # up to one; padded keys are masked, padded query rows sliced away
+    pad_q, pad_k = (-Sq) % block_q, (-Skv) % block_k
+    if pad_q:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
+    if pad_k:
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+    nq, nk = (Sq + pad_q) // block_q, (Skv + pad_k) // block_k
     scale = scale if scale is not None else float(1.0 / np.sqrt(D))
 
     kernel = functools.partial(
@@ -120,8 +132,9 @@ def flash_attention(
         block_q=block_q,
         block_k=block_k,
         num_k_blocks=nk,
+        kv_len=Skv if pad_k else None,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(B, Hq, nq, nk),
         in_specs=[
@@ -130,11 +143,12 @@ def flash_attention(
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, iq, ik: (b, h // group, ik, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
+    return out[:, :, :Sq] if pad_q else out

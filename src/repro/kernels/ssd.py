@@ -23,12 +23,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_kernel(
-    x_ref,   # (1, Q, 1, P)
-    dt_ref,  # (1, Q, 1)
-    a_ref,   # (1,)
-    b_ref,   # (1, Q, 1, N)
-    c_ref,   # (1, Q, 1, N)
-    y_ref,   # (1, Q, 1, P) out
+    x_ref,   # (1, 1, Q, P)
+    dt_ref,  # (1, 1, Q, 1)
+    a_ref,   # (H,) in SMEM
+    b_ref,   # (1, 1, Q, N)
+    c_ref,   # (1, 1, Q, N)
+    y_ref,   # (1, 1, Q, P) out
     hT_ref,  # (1, 1, P, N) out (final state)
     h_ref,   # VMEM scratch (P, N) f32
     *,
@@ -41,28 +41,31 @@ def _ssd_kernel(
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)    # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)     # (Q,)
-    a = a_ref[0].astype(jnp.float32)             # scalar
-    b = b_ref[0, :, 0, :].astype(jnp.float32)    # (Q, N)
-    c = c_ref[0, :, 0, :].astype(jnp.float32)    # (Q, N)
+    x = x_ref[0, 0].astype(jnp.float32)    # (Q, P)
+    dt = dt_ref[0, 0].astype(jnp.float32)  # (Q, 1)
+    a = a_ref[pl.program_id(1)]            # scalar (SMEM)
+    b = b_ref[0, 0].astype(jnp.float32)    # (Q, N)
+    c = c_ref[0, 0].astype(jnp.float32)    # (Q, N)
 
-    la = dt * a  # (Q,) negative log-decays
+    la = dt * a  # (Q, 1) negative log-decays
     # inclusive cumsum via lower-triangular matmul (MXU-friendly)
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-           >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = rows >= cols
     cum = jax.lax.dot_general(
         tri.astype(jnp.float32), la, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )  # (Q,)
+    )  # (Q, 1)
+    # the same values as a row: a diagonal select and a sublane sum, so no
+    # (Q, 1) -> (1, Q) transpose is needed
+    cum_row = jnp.where(rows == cols, cum, 0.0).sum(axis=0, keepdims=True)
 
     # intra-chunk: scores[i,j] = (c_i . b_j) * exp(cum_i - cum_j) for j <= i
-    seg = cum[:, None] - cum[None, :]
-    decay = jnp.where(tri, jnp.exp(seg), 0.0)
+    decay = jnp.where(tri, jnp.exp(cum - cum_row), 0.0)
     cb = jax.lax.dot_general(
         c, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (Q, Q)
-    xdt = x * dt[:, None]
+    xdt = x * dt
     y = jax.lax.dot_general(
         cb * decay, xdt, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -70,20 +73,21 @@ def _ssd_kernel(
 
     # inter-chunk: y += (c * exp(cum)) @ h_prev^T
     h = h_ref[...]  # (P, N)
-    c_in = c * jnp.exp(cum)[:, None]
+    c_in = c * jnp.exp(cum)
     y += jax.lax.dot_general(
         c_in, h, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
 
     # state update: h = exp(cum[-1]) * h + x^T @ (b * (exp(cum[-1]-cum)*dt))
-    tail = jnp.exp(cum[chunk - 1] - cum) * dt  # (Q,)
-    b_in = b * tail[:, None]
-    h_new = jnp.exp(cum[chunk - 1]) * h + jax.lax.dot_general(
+    last = jnp.sum(la)  # == cum[-1], as a scalar: a (1, 1) value cannot
+    #                     broadcast over sublanes and lanes at once
+    b_in = b * (jnp.exp(last - cum) * dt)
+    h_new = jnp.exp(last) * h + jax.lax.dot_general(
         x, b_in, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # (P, N)
     h_ref[...] = h_new
 
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
     @pl.when(ic == num_chunks - 1)
     def _final():
@@ -100,7 +104,12 @@ def ssd_chunked(
     chunk: int = 128,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Returns (y (B,S,H,P), final state (B,H,P,N)); zero initial state."""
+    """Returns (y (B,S,H,P), final state (B,H,P,N)); zero initial state.
+
+    The kernel runs head-major — (B, H, S, P), with dt as a (B, H, S, 1)
+    column — so every block's last two dims are (chunk, features): the
+    (8, 128) tiling Mosaic requires, which a (1, P) head slice is not.
+    """
     Bsz, S, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
     assert H % G == 0
@@ -109,28 +118,31 @@ def ssd_chunked(
     assert S % chunk == 0
     nc = S // chunk
 
+    heads_major = lambda a: jnp.swapaxes(a, 1, 2)  # (B, S, X, .) -> (B, X, S, .)
     kernel = functools.partial(_ssd_kernel, chunk=chunk, num_chunks=nc)
     y, hT = pl.pallas_call(
         kernel,
         grid=(Bsz, H, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda b, h, ic: (b, ic, h, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, h, ic: (b, ic, h)),
-            pl.BlockSpec((1,), lambda b, h, ic: (h,)),
-            pl.BlockSpec((1, chunk, 1, N), lambda b, h, ic: (b, ic, h // rep, 0)),
-            pl.BlockSpec((1, chunk, 1, N), lambda b, h, ic: (b, ic, h // rep, 0)),
+            pl.BlockSpec((1, 1, chunk, P), lambda b, h, ic: (b, h, ic, 0)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, ic: (b, h, ic, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, chunk, N), lambda b, h, ic: (b, h // rep, ic, 0)),
+            pl.BlockSpec((1, 1, chunk, N), lambda b, h, ic: (b, h // rep, ic, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda b, h, ic: (b, ic, h, 0)),
+            pl.BlockSpec((1, 1, chunk, P), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, ic: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bsz, S, H, P), x.dtype),
+            jax.ShapeDtypeStruct((Bsz, H, S, P), x.dtype),
             jax.ShapeDtypeStruct((Bsz, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, Bm, Cm)
+    )(heads_major(x), heads_major(dt[..., None]), A.astype(jnp.float32),
+      heads_major(Bm), heads_major(Cm))
+    y = heads_major(y)
     if D is not None:
         y = (y.astype(jnp.float32)
              + x.astype(jnp.float32) * D.astype(jnp.float32)[None, None, :, None]

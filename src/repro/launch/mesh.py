@@ -6,9 +6,10 @@ only launch/dryrun.py sets the 512-placeholder-device XLA flag).
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType, Mesh
 
 
 def production_mesh_spec(
@@ -34,17 +35,51 @@ def production_mesh_spec(
     return shape, axes + ("stage",)
 
 
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None) -> Mesh:
+    """The one mesh constructor: ``shape`` over ``devices`` (default: the
+    first ``prod(shape)`` visible devices), every axis ``AxisType.Auto``.
+
+    ``jax.make_mesh`` defaults to Explicit axes, which
+    ``with_sharding_constraint`` (``dist.sharding.constrain``) refuses.
+    """
+    if devices is None:
+        n = 1
+        for s in shape:
+            n *= int(s)
+        devices = jax.devices()[:n]
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=list(devices))
+
+
+def rank_mesh(axis: str) -> Mesh:
+    """The 1-D mesh a rank backend uses when its caller passes none.
+
+    Only on the CPU, where the caller fixed the device count before JAX
+    started (``JAX_NUM_CPU_DEVICES``), so every visible device is one it
+    asked for.  On an accelerator the devices are chips that another run
+    may need, and the caller names them: this raises.
+    """
+    devices = jax.devices()
+    if devices[0].platform != "cpu":
+        raise ValueError(
+            f"on {devices[0].platform} a rank backend needs mesh=: pass "
+            f"make_mesh((n,), ({axis!r},), jax.devices()[:n])")
+    return make_mesh((len(devices),), (axis,), devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False, pipeline_stages: int = 1):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips),
     optionally with a ``stage`` pipeline axis."""
     shape, axes = production_mesh_spec(
         multi_pod=multi_pod, pipeline_stages=pipeline_stages)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh over however many local devices tests spawned."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def moe_dispatch_planes(mesh_shape, ep_mode: str) -> int:

@@ -1,4 +1,4 @@
-"""The JAX compat shim, plus a guard against bypassing it."""
+"""The shard_map/pcast shim, plus a guard against bypassing it."""
 import os
 
 import jax
@@ -20,7 +20,7 @@ def test_shard_map_runs_on_one_device():
 
 
 def test_shard_map_accepts_check_vma_kwarg():
-    """check_vma must be translated to check_rep on legacy JAX."""
+    """check_vma passes through to jax.shard_map."""
     mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
     f = shard_map(lambda v: v * 2, mesh=mesh, in_specs=P("d"),
                   out_specs=P("d"), check_vma=False)
@@ -29,16 +29,20 @@ def test_shard_map_accepts_check_vma_kwarg():
 
 
 def test_pcast_is_usable_outside_shard_map_semantics():
-    """On legacy JAX pcast is the identity; either way values round-trip."""
+    """pcast accepts a value that is already varying (a sharded input)
+    and casts one that is not (a replicated input); values round-trip."""
     mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
 
-    def body(v):
+    def body(v, r):
         v = pcast(v, ("d",), to="varying")
-        return v + 1
+        r = pcast(r, ("d",), to="varying")
+        assert jax.typeof(r).vma == {"d"}
+        return v + r
 
-    f = shard_map(body, mesh=mesh, in_specs=P("d"), out_specs=P("d"))
+    f = shard_map(body, mesh=mesh, in_specs=(P("d"), P()), out_specs=P("d"))
     x = jnp.zeros((4,), jnp.float32)
-    np.testing.assert_array_equal(np.asarray(f(x)), np.ones(4))
+    np.testing.assert_array_equal(np.asarray(f(x, jnp.ones((4,)))),
+                                  np.ones(4))
 
 
 def test_no_direct_jax_shard_map_references_in_src():

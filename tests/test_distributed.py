@@ -140,7 +140,8 @@ params, _ = split_leaves(M.init_model(jax.random.PRNGKey(0), cfg))
 toks = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab_size)
 ref_logits, _, _ = M.forward(params, cfg, tokens=toks)
 
-mesh = jax.make_mesh((1, 2, 2, 2), ("pod", "data", "model", "stage"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((1, 2, 2, 2), ("pod", "data", "model", "stage"))
 rules = make_rules(mesh)
 pp_params = PP.stack_params_by_stage(params, num_stages=2)
 with mesh, use_rules(rules):
@@ -165,7 +166,8 @@ from repro.train import train_step as TS, dist_step as DS
 cfg = reduced(get_config("qwen1.5-0.5b"))
 tcfg = TS.TrainConfig(base_lr=1e-3, warmup_steps=2, total_steps=40)
 dcfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=16, global_batch=16)
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 
 def run(fn, steps=3):
     state, _ = TS.init_state(jax.random.PRNGKey(0), cfg, tcfg)
@@ -225,7 +227,8 @@ cfg = dataclasses.replace(reduced(get_config("mixtral-8x7b")),
                           moe_capacity_factor=8.0)
 assert cfg.ep_mode == "sp"
 for shape in ((4, 2), (2, 4)):
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh(shape, ("data", "model"))
     rules = make_rules(mesh)
     params, _ = split_leaves(MO.init_moe(jax.random.PRNGKey(0), cfg))
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model),
@@ -299,7 +302,8 @@ import dataclasses
 
 cfg = reduced(get_config("mixtral-8x7b"))
 cfg = dataclasses.replace(cfg, moe_capacity_factor=8.0)  # no drops
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 rules = make_rules(mesh)
 p_leaf = MO.init_moe(jax.random.PRNGKey(0), cfg)
 params, _ = split_leaves(p_leaf)
@@ -329,7 +333,8 @@ import dataclasses
 
 cfg = dataclasses.replace(reduced(get_config("mixtral-8x7b")),
                           moe_capacity_factor=8.0)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 rules = make_rules(mesh)
 params, _ = split_leaves(MO.init_moe(jax.random.PRNGKey(0), cfg))
 x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model))
@@ -357,7 +362,8 @@ import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.compat import shard_map
 from repro.dist.compression import compressed_psum
-mesh = jax.make_mesh((8,), ("d",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("d",))
 x = np.random.RandomState(0).randn(8, 64).astype(np.float32)
 f = jax.jit(shard_map(lambda v: compressed_psum(v, "d"),
     mesh=mesh, in_specs=P("d"), out_specs=P("d")))
@@ -382,7 +388,8 @@ from repro.optim import adamw
 from repro.train import train_step as TS
 
 cfg = reduced(get_config("yi-6b"))
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 rules = make_rules(mesh)
 shape = InputShape("tiny_train", 64, 8, "train")
 with mesh, use_rules(rules):

@@ -40,6 +40,9 @@ ATTN_CASES = [
     (2, 64, 64, 4, 1, 64, False, None, 0, jnp.float32),
     (1, 256, 256, 2, 2, 128, True, 128, 0, jnp.bfloat16),
     (2, 128, 128, 6, 3, 64, True, None, 0, jnp.float32),
+    # lengths that are not a multiple of the block: padded and masked
+    (1, 200, 200, 4, 2, 64, True, None, 0, jnp.float32),
+    (2, 100, 150, 4, 4, 32, False, None, 0, jnp.float32),
 ]
 
 

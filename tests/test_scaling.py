@@ -244,3 +244,27 @@ def test_scaling_backends_are_multirank_only():
     assert set(SCALING_BACKENDS) == {
         "shardmap-csp", "shardmap-csp[comm=onesided]",
         "shardmap-pipeline", "shardmap-pipeline[comm=onesided]", "auto"}
+
+
+def test_run_scaling_in_process_over_given_devices():
+    """With ``devices`` (the TPU path) every rank count runs in this
+    process on a backend built over ``devices[:n]`` — no child."""
+    import jax
+
+    from repro.bench.scenario import SweepControls
+    from repro.bench.sweep import run_scenario
+
+    spec = ScalingSpec(name="metg_scaling.inproc", backend="shardmap-csp",
+                       ranks=(1,), height=4,
+                       sweep=SweepControls(schedule=(16, 1), repeats=1))
+    result = run_scaling(spec, smoke=True, devices=jax.devices()[:1],
+                         python="/nonexistent/python")
+    (cell,) = result.cells
+    assert cell["ranks"] == cell["devices"] == 1
+    assert result.doc["timer"] == "wallclock"
+    with pytest.raises(ValueError, match="give your timer its own devices"):
+        run_scenario(spec.scenario_for(1, smoke=True),
+                     timer=SyntheticTimer(), devices=jax.devices()[:1])
+    with pytest.raises(ValueError, match="need 2 devices"):
+        run_scaling(ScalingSpec(name="s", ranks=(1, 2)), smoke=True,
+                    devices=jax.devices()[:1])

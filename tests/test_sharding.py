@@ -83,3 +83,22 @@ def test_dp_only_strategy():
     r = make_rules(fake_mesh(data=16, model=16), strategy="dp_only")
     assert r.spec_for(("embed", "ffn"), (4096, 14336)) == P(None, None)
     assert r.spec_for(("batch", "seq"), (256, 4096)) == P("data", None)
+
+
+def test_make_mesh_axes_accept_sharding_constraints():
+    """Every mesh comes from launch.mesh.make_mesh with Auto axes, which
+    ``constrain`` (with_sharding_constraint) accepts; jax.make_mesh's
+    default Explicit axes are refused."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import AxisType
+
+    from repro.dist.sharding import constrain, use_rules
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+    with mesh, use_rules(make_rules(mesh)):
+        y = jax.jit(lambda x: constrain(x, "batch", "embed") * 2)(
+            jnp.ones((4, 8)))
+    assert float(y.sum()) == 64.0

@@ -249,3 +249,40 @@ def test_load_metg_artifacts_warns_and_counts_skips(tmp_path, capsys):
     path, skipped = append_tables.append_metg_tables(str(tmp_path), str(md))
     assert path == str(md) and skipped == 1
     assert "METG(50%)" in md.read_text()
+
+
+def test_device_cells_never_share_the_device(tmp_path):
+    """A cell that times the device (any timer but the synthetic one)
+    runs with no other cell beside it, whatever ``parallel`` says: a chip
+    belongs to one process at a time."""
+    import json
+    import sys
+
+    fake = tmp_path / "benchmarks"
+    fake.mkdir()
+    (fake / "__init__.py").write_text("")
+    (fake / "run.py").write_text(
+        "import json, os, sys, time\n"
+        "a = sys.argv[1:]\n"
+        "out = a[a.index('--artifacts') + 1] + '.' + a[a.index('--only') + 1]\n"
+        "t0 = time.time(); time.sleep(0.4)\n"
+        "with open(out + '.span', 'w') as f:\n"
+        "    json.dump([a[a.index('--timer') + 1], t0, time.time()], f)\n")
+    suite = parse_suite(
+        'name="s"\nparallel=4\ntimer="synthetic"\n'
+        + "".join(f'[[tasks]]\nfamily="{f}"\n' + (
+            'timer="wallclock"\n' if i % 2 else "")
+            for i, f in enumerate(["a", "b", "c", "d"])))
+    res = run_suite(suite, str(tmp_path / "out"), cwd=str(tmp_path),
+                    python=sys.executable)
+    assert res.ok, res.summary()
+    spans = []
+    for cr in res.runs:
+        with open(f"{cr.out_dir}.{cr.cell.family}.span") as f:
+            spans.append(json.load(f))
+    assert len(spans) == 4
+    for i, (timer, t0, t1) in enumerate(spans):
+        if timer == "synthetic":
+            continue
+        for j, (_, u0, u1) in enumerate(spans):
+            assert i == j or u1 <= t0 or t1 <= u0, (spans[i], spans[j])

@@ -314,6 +314,32 @@ def test_auto_resolves_with_zero_per_dispatch_measurement():
     assert list(be._delegates) == ["pallas-fused"]  # cached on execution
 
 
+def _tpu_rank_mesh(axis):
+    raise ValueError("on tpu a rank backend needs mesh=")
+
+
+@pytest.mark.parametrize("winner", ["shardmap-csp[comm=onesided]",
+                                    "pallas-fused[comm=onesided]"])
+def test_rank_winners_run_where_rank_backends_need_devices(monkeypatch,
+                                                           winner):
+    """On a TPU a rank backend built without devices refuses to guess
+    them (``launch.mesh.rank_mesh`` raises); auto and the wall clock name
+    the devices, so a rank winner still runs."""
+    from repro.backends import csp, megakernel
+    from repro.bench.timers import WallClockTimer
+
+    monkeypatch.setattr(csp, "rank_mesh", _tpu_rank_mesh)
+    monkeypatch.setattr(megakernel, "rank_mesh", _tpu_rank_mesh)
+    with pytest.raises(ValueError, match="needs mesh="):
+        get_backend(winner)
+    g = make_graph(width=6, height=8, pattern="stencil")
+    be = get_backend("auto")
+    monkeypatch.setattr(be, "resolve_spec", lambda graphs: winner)
+    check_outputs(g, be.run([g])[0], expected=execute_reference(g))
+    assert WallClockTimer(warmup=0, repeats=1).measure(winner, [g]) > 0
+    assert winner in backend_mode_specs(winner.split("[")[0])
+
+
 def test_synthetic_timer_charges_auto_as_its_resolved_backend():
     """The fake clock treats auto as the planner, not a cost model: an
     auto measurement equals the resolved winner's measurement exactly."""
