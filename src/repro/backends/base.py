@@ -2,16 +2,19 @@
 
 Each backend executes a list of concurrent task graphs (paper: multiple
 graphs model task parallelism) and returns the final-timestep payload of
-each.  ``runner`` returns a zero-arg callable that re-executes the prepared
-workload and blocks until completion — the METG harness times that.
+each.  ``prepare`` returns a ``Runner``: a zero-arg callable that
+re-executes the prepared workload and returns once the payloads are on the
+host — the METG harness times that.
 """
 from __future__ import annotations
 
 import ast
 import inspect
 import re
-from typing import Callable, Dict, List, Optional, Sequence, Tuple, Type
+from dataclasses import dataclass
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Type
 
+import jax
 import numpy as np
 
 from ..core.graph import TaskGraph
@@ -167,6 +170,50 @@ def get_backend(name: str, devices: Optional[Sequence] = None,
     return cls(**merged)
 
 
+# The host spans of one graph run, in this order on the calling thread.
+# With no profiler session a span costs under a microsecond.
+LAUNCH = "taskbench.runner.launch"
+WAIT = "taskbench.runner.wait"
+READBACK = "taskbench.runner.readback"
+
+
+def to_host(outs) -> List[np.ndarray]:
+    """Each of the device arrays ``outs`` copied to the host."""
+    return [np.asarray(o) for o in outs]
+
+
+@dataclass(frozen=True)
+class Runner:
+    """A prepared graph run: each call runs it again and returns each
+    graph's final payload on the host, in three host spans
+    (``jax.profiler.TraceAnnotation``) that follow one another:
+
+    * ``taskbench.runner.launch``: ``launch()``, argument handling and the
+      enqueue of the program (for host dispatch, the whole dispatch loop);
+    * ``taskbench.runner.wait``: the host blocked until every output exists;
+    * ``taskbench.runner.readback``: ``readback(outputs)``, the copy to the
+      host and any slicing.
+    """
+
+    launch: Callable[[], Any]
+    readback: Callable[[Any], List[np.ndarray]] = to_host
+
+    def __call__(self) -> List[np.ndarray]:
+        with jax.profiler.TraceAnnotation(LAUNCH):
+            outs = self.launch()
+        with jax.profiler.TraceAnnotation(WAIT):
+            outs = jax.block_until_ready(outs)
+        with jax.profiler.TraceAnnotation(READBACK):
+            return self.readback(outs)
+
+
+def in_turn(runners: Sequence[Runner]) -> Callable[[], List[np.ndarray]]:
+    """Independent runs: each starts once the one before is read back."""
+    if len(runners) == 1:
+        return runners[0]
+    return lambda: [out for r in runners for out in r()]
+
+
 class Backend:
     """Executes task graphs. Subclasses implement ``prepare``.
 
@@ -204,7 +251,12 @@ class Backend:
         return {}
 
     def prepare(self, graphs: Sequence[TaskGraph]) -> Callable[[], List[np.ndarray]]:
-        """Compile/stage the workload; returned callable blocks on finish."""
+        """Compile/stage the workload.  The returned callable is a
+        ``Runner`` (or ``in_turn`` of one per program): its launch, wait
+        and readback phases each run in a host span of their own, so a
+        trace tells the host's launch, its wait on the chip and the copy
+        back apart.  A new backend builds a ``Runner``, not a runner of
+        its own."""
         raise NotImplementedError
 
     def prepare_many(self, graphs: Sequence[TaskGraph]) -> Callable[[], List[np.ndarray]]:
@@ -256,31 +308,24 @@ class StackedProgramBackend(Backend):
         fn, *args = built
         return (fn.lower(*args).compile(), *args)
 
-    def prepare(self, graphs: Sequence[TaskGraph]):
-        import jax
-
+    def prepare(self, graphs: Sequence[TaskGraph]) -> Runner:
+        """One compiled program for all ``graphs``, run as a ``Runner``
+        (launch, wait, readback spans)."""
         compiled, *args = self._compile(graphs)
+        return Runner(lambda: compiled(*args))
 
-        def runner() -> List[np.ndarray]:
-            outs = compiled(*args)
-            return [np.asarray(jax.block_until_ready(o)) for o in outs]
-
-        return runner
-
-    def prepare_many(self, graphs: Sequence[TaskGraph]):
-        import jax
-
+    def prepare_many(self, graphs: Sequence[TaskGraph]) -> Runner:
         graphs = list(graphs)
         built = self._compile_stacked(graphs)
         if built is None:
             return self.prepare(graphs)
         compiled, *args = built
 
-        def runner() -> List[np.ndarray]:
-            out = np.asarray(jax.block_until_ready(compiled(*args)))
+        def readback(stacked) -> List[np.ndarray]:
+            out = np.asarray(stacked)
             return [out[k] for k in range(out.shape[0])]
 
-        return runner
+        return Runner(lambda: compiled(*args), readback)
 
     def lowered_hlo(self, graphs: Sequence[TaskGraph]) -> List[str]:
         graphs = list(graphs)

@@ -84,16 +84,25 @@ def timestep(graph: TaskGraph, t, prev_payload, dep_matrix, iters_per_col,
     iters_per_col:(n,) int32 — per-task durations (imbalance-aware).
     cols:         (n,) global column ids (defaults to arange(W_ctx)).
     Returns the new (n, P) payload block.
+
+    Its parts carry the named scopes ``combine``, ``checksum``, ``kernel``
+    and ``payload``: they name the compiled ops (``op_name`` metadata), so
+    a device trace tells them apart.  They change no computation.
     """
     if cols is None:
         cols = jnp.arange(graph.width)
-    prev_combined = prev_payload[:, 3].astype(jnp.uint32)
-    acc = combine_acc(dep_matrix, prev_combined)
-    base = checksum_vec(t, cols)
-    combined = (base + acc) % jnp.uint32(CHECKSUM_MOD)
-    result = run_kernel_vec(graph.kernel, iters_per_col, acc,
-                            graph.kernel.iterations, dynamic=dynamic)
-    return make_payload(t, cols, base, combined, result, graph.payload_elems)
+    with jax.named_scope("combine"):
+        prev_combined = prev_payload[:, 3].astype(jnp.uint32)
+        acc = combine_acc(dep_matrix, prev_combined)
+    with jax.named_scope("checksum"):
+        base = checksum_vec(t, cols)
+        combined = (base + acc) % jnp.uint32(CHECKSUM_MOD)
+    with jax.named_scope("kernel"):
+        result = run_kernel_vec(graph.kernel, iters_per_col, acc,
+                                graph.kernel.iterations, dynamic=dynamic)
+    with jax.named_scope("payload"):
+        return make_payload(t, cols, base, combined, result,
+                            graph.payload_elems)
 
 
 def graph_static_inputs(graph: TaskGraph) -> Tuple[np.ndarray, np.ndarray]:

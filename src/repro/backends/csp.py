@@ -50,7 +50,7 @@ from ..core.graph import TaskGraph
 from ..dist import collectives as CC
 from ..launch.mesh import make_mesh, rank_mesh
 from . import body
-from .base import Backend, register_backend
+from .base import Backend, Runner, in_turn, register_backend
 
 AXIS = "cols"
 
@@ -88,13 +88,7 @@ class PlannedSPMDBackend(Backend):
                             comm_overlap=self.comm_overlap)
 
     def prepare(self, graphs: Sequence[TaskGraph]):
-        progs = [self._prepare_one(g) for g in graphs]
-
-        def runner() -> List[np.ndarray]:
-            outs = [p() for p in progs]
-            return [np.asarray(o) for o in outs]
-
-        return runner
+        return in_turn([self._prepare_one(g) for g in graphs])
 
     def _compile_one(self, graph: TaskGraph):
         plan = self.plan(graph)
@@ -190,14 +184,10 @@ class PlannedSPMDBackend(Backend):
         compiled = fn.lower(lmats_j, iters_j).compile()
         return compiled, plan, lmats_j, iters_j
 
-    def _prepare_one(self, graph: TaskGraph):
+    def _prepare_one(self, graph: TaskGraph) -> Runner:
         compiled, plan, lmats_j, iters_j = self._compile_one(graph)
-
-        def run_one():
-            out = jax.block_until_ready(compiled(lmats_j, iters_j))
-            return plan.trim(out)
-
-        return run_one
+        return Runner(lambda: compiled(lmats_j, iters_j),
+                      lambda out: [plan.trim(np.asarray(out))])
 
     def _compile_combined(self, graphs: Sequence[TaskGraph]):
         """One shard_map program interleaving every graph's wavefront.
@@ -329,12 +319,9 @@ class PlannedSPMDBackend(Backend):
         if built is None:
             return self.prepare(graphs)
         compiled, plans, lmats, iters = built
-
-        def runner() -> List[np.ndarray]:
-            outs = jax.block_until_ready(compiled(lmats, iters))
-            return [np.asarray(p.trim(o)) for p, o in zip(plans, outs)]
-
-        return runner
+        return Runner(lambda: compiled(lmats, iters),
+                      lambda outs: [p.trim(np.asarray(o))
+                                    for p, o in zip(plans, outs)])
 
     def lowered_hlo(self, graphs: Sequence[TaskGraph]) -> List[str]:
         graphs = list(graphs)

@@ -34,7 +34,7 @@ import numpy as np
 from ..core.graph import CHECKSUM_MOD, TaskGraph
 from ..core.schedule import steal_schedule
 from . import body
-from .base import Backend, register_backend
+from .base import Backend, Runner, in_turn, register_backend
 
 SCHEDULES = ("static", "steal")
 
@@ -101,25 +101,25 @@ class HostBackend(Backend):
             store.pop((t - 2, i), None)
 
     def prepare(self, graphs: Sequence[TaskGraph]):
-        task_fns = [self._compile_task(g) for g in graphs]
-        statics = [body.graph_static_inputs(g) for g in graphs]
-        orders = [self._wavefront_orders(g, iters)
-                  for g, (mats, iters) in zip(graphs, statics)]
+        """One ``Runner`` per graph, run in turn; a graph's launch span
+        holds its whole dispatch loop."""
+        return in_turn([self._prepare_one(g) for g in graphs])
 
-        def runner() -> List[np.ndarray]:
-            finals: List[np.ndarray] = []
-            for g, fn, (mats, iters), g_orders in zip(
-                    graphs, task_fns, statics, orders):
-                radix = max(1, g.max_radix())
-                store: Dict[Tuple[int, int], jax.Array] = {}
-                for t in range(g.height):
-                    self._dispatch_timestep(g, fn, iters, store, t, radix,
-                                            g_orders[t])
-                row = jnp.stack([store[(g.height - 1, i)] for i in range(g.width)])
-                finals.append(np.asarray(jax.block_until_ready(row)))
-            return finals
+    def _prepare_one(self, g: TaskGraph) -> Runner:
+        fn = self._compile_task(g)
+        _, iters = body.graph_static_inputs(g)
+        orders = self._wavefront_orders(g, iters)
+        radix = max(1, g.max_radix())
 
-        return runner
+        def launch() -> List[jax.Array]:
+            store: Dict[Tuple[int, int], jax.Array] = {}
+            for t in range(g.height):
+                self._dispatch_timestep(g, fn, iters, store, t, radix,
+                                        orders[t])
+            return [jnp.stack([store[(g.height - 1, i)]
+                               for i in range(g.width)])]
+
+        return Runner(launch)
 
     def prepare_many(self, graphs: Sequence[TaskGraph]):
         """Concurrent execution: wavefronts of the graphs interleave.
@@ -139,7 +139,7 @@ class HostBackend(Backend):
         orders = [self._wavefront_orders(g, iters)
                   for g, (mats, iters) in zip(graphs, statics)]
 
-        def runner() -> List[np.ndarray]:
+        def launch() -> List[jax.Array]:
             stores: List[Dict[Tuple[int, int], jax.Array]] = [
                 {} for _ in graphs]
             for t in range(max(g.height for g in graphs)):
@@ -148,14 +148,11 @@ class HostBackend(Backend):
                     if t < g.height:
                         self._dispatch_timestep(g, fn, iters, store, t, radix,
                                                 g_orders[t])
-            finals: List[np.ndarray] = []
-            for g, store in zip(graphs, stores):
-                row = jnp.stack(
-                    [store[(g.height - 1, i)] for i in range(g.width)])
-                finals.append(np.asarray(jax.block_until_ready(row)))
-            return finals
+            return [jnp.stack([store[(g.height - 1, i)]
+                               for i in range(g.width)])
+                    for g, store in zip(graphs, stores)]
 
-        return runner
+        return Runner(launch)
 
     @staticmethod
     def _compile_task(graph: TaskGraph):

@@ -353,6 +353,7 @@ class MegakernelBackend(StackedProgramBackend):
             out_shape=jax.ShapeDtypeStruct((ngraphs * W, P), jnp.float32),
             scratch_shapes=_memory_scratch(g0.kernel, W),
             interpret=interpret,
+            name="taskbench_megakernel",
         )
 
     # -- one-sided (distributed) tables and program ------------------------
@@ -454,6 +455,7 @@ class MegakernelBackend(StackedProgramBackend):
             out_shape=jax.ShapeDtypeStruct((local, Pels), jnp.float32),
             scratch_shapes=scratch,
             interpret=interpret,
+            name="taskbench_megakernel_onesided",
         )
 
     def _program_onesided(self, graphs: List[TaskGraph], interpret: bool):

@@ -12,6 +12,7 @@ file.  The persistent compilation cache is off around these compiles.
 """
 import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -76,6 +77,22 @@ def test_pallas_fused_compiles(one_chip, pattern, kernel, width, ngraphs):
                    **kw)
     text = _compile_fused([g] * ngraphs, one_chip).as_text()
     assert text.count("tpu_custom_call") >= 1
+    # the kernel's name names its op in a device trace
+    assert "%taskbench_megakernel" in text
+
+
+def test_xla_scan_ops_carry_the_task_step_scopes(one_chip):
+    """At the benchmark cell's size (W=128, H=1000) the compiled program's
+    ops name the task step's parts, so a device trace can split them."""
+    g = make_graph(width=128, height=1000, pattern="stencil", kernel="compute",
+                   iterations=1)
+    fn, *args = get_backend("xla-scan")._build([g])
+    text = fn.lower(*_shapes(args, one_chip)).compile().as_text()
+    ops = dict(re.findall(r'%([^\s=]+) = [^\n]*?op_name="([^"]*)"', text))
+    assert any("fusion" in n and "/combine/" in o
+               for n, o in ops.items())
+    for scope in ("combine", "checksum", "kernel", "payload"):
+        assert any(f"/{scope}/" in o for o in ops.values()), scope
 
 
 def test_flash_attention_compiles(one_chip):
@@ -139,5 +156,6 @@ def test_pallas_fused_onesided_compiles_on_four_chips(topo, pattern):
     assert "Precision.HIGHEST" in str(traced.jaxpr)
     text = traced.lower().compile().as_text()
     assert "tpu_custom_call" in text
+    assert "%taskbench_megakernel_onesided" in text
     for op in ("all-gather", "all-to-all", "collective-permute"):
         assert op not in text, op
