@@ -1,13 +1,17 @@
-"""Scan backend: ``lax.scan`` over timesteps, columns vectorized.
+"""Scan backend: a compiled loop over timesteps, columns vectorized.
 
 Analogue of the paper's vectorized on-node runtimes (OpenMP forall /
-MPI+OpenMP inner loop): one compiled timestep body re-executed H times.
-Compile cost is O(1) in graph height (unlike xla-static), at the price of a
-loop-carried schedule that XLA cannot fuse across timesteps.
+MPI+OpenMP inner loop).  One trip of the loop runs a block of ``BLOCK``
+consecutive timesteps, each the shared task body unchanged, so the loop's
+control and the slice of the dependency and iteration tables are paid once
+per block; the ``H % BLOCK`` steps left over run as straight-line code
+after the loop.  Compile cost does not grow with graph height (unlike
+xla-static), at the price of a loop-carried schedule: every step finishes
+its kernel before the next step's combine starts.
 """
 from __future__ import annotations
 
-from typing import List, Sequence
+from typing import Callable, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -17,58 +21,99 @@ from ..core.graph import TaskGraph
 from . import body
 from .base import StackedProgramBackend, register_backend
 
+# Timesteps one trip of the loop runs (capped at the graph's height).  On a
+# TPU v5e at W=128, 8 ran the benchmark's stencil sweep faster than 4 or 16
+# at 2 to 8 iterations a task, where METG(50%) falls (PERF.md).
+BLOCK = 8
+
+
+def loop_shape(height: int) -> Tuple[int, int]:
+    """(trips, steps per trip) of the timestep loop over ``height`` steps;
+    the ``height - trips * steps`` steps left over follow the loop."""
+    steps = min(BLOCK, height)
+    return height // steps, steps
+
+
+def blocked_tables(height: int, *tables: np.ndarray):
+    """The timestep numbers and each ``(H, ...)`` table, split once into
+    the loop's ``(trips, steps, ...)`` blocks and the ``(H % steps, ...)``
+    tail that follows the loop: ``((ts, *tables), (ts, *tables))``."""
+    trips, steps = loop_shape(height)
+    n = trips * steps
+    tables = (np.arange(height, dtype=np.uint32),) + tables
+    blocks = tuple(jnp.asarray(a[:n].reshape((trips, steps) + a.shape[1:]))
+                   for a in tables)
+    tail = tuple(jnp.asarray(a[n:]) for a in tables)
+    return blocks, tail
+
+
+def timestep_loop(step: Callable, init, blocks, tail):
+    """Run ``step(payload, t, mat, iters) -> payload`` over every timestep
+    of ``blocked_tables``' output: one loop trip a block, the tail after.
+
+    Each step's payload passes an optimization barrier before the next
+    step reads it: the next combine reads one column of it, and without
+    the barrier XLA would drop the kernel of every step but a block's
+    last, whose result only the loop carry keeps."""
+
+    def run(payload, ts, mats, iters):
+        for j in range(ts.shape[0]):
+            payload = jax.lax.optimization_barrier(
+                step(payload, ts[j], mats[j], iters[j]))
+        return payload
+
+    payload, _ = jax.lax.scan(lambda p, xs: (run(p, *xs), None), init,
+                              blocks)
+    return run(payload, *tail)
+
 
 @register_backend("xla-scan")
 class ScanBackend(StackedProgramBackend):
     paradigm = "compiled timestep loop (OpenMP-forall analogue)"
 
+    loop_shape = staticmethod(loop_shape)
+
     def _build(self, graphs: Sequence[TaskGraph]):
-        """One program scanning each graph in turn (independent execution)."""
-        statics = [body.graph_static_inputs(g) for g in graphs]
+        """One program looping over each graph in turn (independent
+        execution)."""
+        tables = [blocked_tables(g.height, *body.graph_static_inputs(g))
+                  for g in graphs]
 
-        def program(all_mats, all_iters):
+        def program(all_tables):
             outs = []
-            for g, mats, iters in zip(graphs, all_mats, all_iters):
+            for g, (blocks, tail) in zip(graphs, all_tables):
                 init = jnp.zeros((g.width, g.payload_elems), jnp.float32)
-                ts = jnp.arange(g.height, dtype=jnp.uint32)
 
-                def step(payload, xs):
-                    t, mat, it = xs
-                    new = body.timestep(g, t, payload, mat, it)
-                    return new, None
+                def step(payload, t, mat, it):
+                    return body.timestep(g, t, payload, mat, it)
 
-                final, _ = jax.lax.scan(step, init, (ts, mats, iters))
-                outs.append(final)
+                outs.append(timestep_loop(step, init, blocks, tail))
             return outs
 
-        mats_in = [jnp.asarray(m) for m, _ in statics]
-        iters_in = [jnp.asarray(i) for _, i in statics]
-        return jax.jit(program), mats_in, iters_in
+        return jax.jit(program), tables
 
     def _build_stacked(self, graphs: Sequence[TaskGraph]):
-        """One scan over a stacked (graph, width) payload — the concurrent
+        """One loop over a stacked (graph, width) payload — the concurrent
         form: all graphs advance in the same compiled timestep (multi-graph
         scenarios, paper Fig 9d).  None if the graphs cannot share a body."""
         if not body.stackable(graphs):
             return None
         g0 = graphs[0]
         mats, iters = body.stacked_static_inputs(graphs)
-        mats_t = jnp.asarray(mats.transpose(1, 0, 2, 3))  # (H, G, W, W)
-        iters_t = jnp.asarray(iters.transpose(1, 0, 2))   # (H, G, W)
+        blocks, tail = blocked_tables(
+            g0.height,
+            mats.transpose(1, 0, 2, 3),  # (H, G, W, W)
+            iters.transpose(1, 0, 2))    # (H, G, W)
 
-        def program(mats_a, iters_a):
+        def program(blocks, tail):
             init = jnp.zeros((len(graphs), g0.width, g0.payload_elems),
                              jnp.float32)
-            ts = jnp.arange(g0.height, dtype=jnp.uint32)
 
-            def step(payload, xs):
-                t, mat, it = xs
-                new = jax.vmap(
+            def step(payload, t, mat, it):
+                return jax.vmap(
                     lambda p, m, iv: body.timestep(g0, t, p, m, iv)
                 )(payload, mat, it)
-                return new, None
 
-            final, _ = jax.lax.scan(step, init, (ts, mats_a, iters_a))
-            return final
+            return timestep_loop(step, init, blocks, tail)
 
-        return jax.jit(program), mats_t, iters_t
+        return jax.jit(program), blocks, tail

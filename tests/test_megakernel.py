@@ -48,7 +48,7 @@ def test_fused_program_is_a_single_kernel_launch():
     """The tentpole, pinned structurally: all H timesteps of the graph
     lower into exactly one Pallas launch (`tpu_custom_call`) and no
     dispatch loop, while xla-scan's program is a `stablehlo.while` that
-    re-dispatches its body every timestep."""
+    dispatches the ops of a block of timesteps again on every trip."""
     g = small_graph()
     fused = get_backend("pallas-fused").lowered_stablehlo([g])
     assert fused.count("tpu_custom_call") == 1
@@ -62,7 +62,7 @@ def test_fused_program_is_a_single_kernel_launch():
 def test_fused_concurrent_graphs_still_one_launch():
     """Multi-graph scenarios fuse through the leading grid dimension:
     even 3 concurrent graphs cost ONE launch (xla-scan pays one while
-    loop regardless, but each iteration dispatches its ops again)."""
+    loop regardless, but each trip dispatches its block's ops again)."""
     g = small_graph()
     fused = get_backend("pallas-fused").lowered_stablehlo(replicate(g, 3))
     assert fused.count("tpu_custom_call") == 1
