@@ -43,7 +43,7 @@ from typing import List, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..compat import pcast, shard_map
 from ..core.graph import TaskGraph
@@ -90,11 +90,25 @@ class PlannedSPMDBackend(Backend):
     def prepare(self, graphs: Sequence[TaskGraph]):
         return in_turn([self._prepare_one(g) for g in graphs])
 
-    def _compile_one(self, graph: TaskGraph):
+    def _table_specs(self):
+        """How a plan's (local_mats, iters) lie on the mesh: each rank
+        holds its own columns of every timestep."""
+        return P(None, self.axis, None), P(None, self.axis)
+
+    def _resident(self, plan: CC.CommPlan):
+        """``plan``'s tables placed on the mesh once, in the shardings the
+        rank program takes them in, so that a run moves no table."""
+        mats_spec, iters_spec = self._table_specs()
+        return (jax.device_put(plan.local_mats,
+                               NamedSharding(self.mesh, mats_spec)),
+                jax.device_put(plan.iters,
+                               NamedSharding(self.mesh, iters_spec)))
+
+    def _program_one(self, graph: TaskGraph):
+        """The jitted rank program of ``graph`` and its plan; it takes the
+        plan's (local_mats, iters) in ``_table_specs``."""
         plan = self.plan(graph)
         local, Pels = plan.local, graph.payload_elems
-        lmats_j = jnp.asarray(plan.local_mats)
-        iters_j = jnp.asarray(plan.iters)
         dynamic = local == 1  # true per-rank loops can stop early
 
         def rank_program(lmats_l, iters_l):
@@ -177,10 +191,14 @@ class PlannedSPMDBackend(Backend):
         shmapped = shard_map(
             rank_program,
             mesh=self.mesh,
-            in_specs=(P(None, self.axis, None), P(None, self.axis)),
+            in_specs=self._table_specs(),
             out_specs=P(self.axis, None),
         )
-        fn = jax.jit(shmapped)
+        return jax.jit(shmapped), plan
+
+    def _compile_one(self, graph: TaskGraph):
+        fn, plan = self._program_one(graph)
+        lmats_j, iters_j = self._resident(plan)
         compiled = fn.lower(lmats_j, iters_j).compile()
         return compiled, plan, lmats_j, iters_j
 
@@ -202,8 +220,9 @@ class PlannedSPMDBackend(Backend):
         plans = [self.plan(g) for g in graphs]
         height = graphs[0].height
         dynamics = [p.local == 1 for p in plans]
-        lmats = tuple(jnp.asarray(p.local_mats) for p in plans)
-        iters = tuple(jnp.asarray(p.iters) for p in plans)
+        placed = [self._resident(p) for p in plans]
+        lmats = tuple(m for m, _ in placed)
+        iters = tuple(i for _, i in placed)
 
         def rank_program(lmats_l, iters_l):
             colss = tuple(p.local_cols() for p in plans)
@@ -303,11 +322,12 @@ class PlannedSPMDBackend(Backend):
             final, _ = jax.lax.scan(step, payloads, (ts, lmats_l, iters_l))
             return final
 
+        mats_spec, iters_spec = self._table_specs()
         shmapped = shard_map(
             rank_program,
             mesh=self.mesh,
-            in_specs=(tuple(P(None, self.axis, None) for _ in plans),
-                      tuple(P(None, self.axis) for _ in plans)),
+            in_specs=(tuple(mats_spec for _ in plans),
+                      tuple(iters_spec for _ in plans)),
             out_specs=tuple(P(self.axis, None) for _ in plans),
         )
         compiled = jax.jit(shmapped).lower(lmats, iters).compile()

@@ -158,36 +158,39 @@ class CommPlan:
         payload: (local, P) f32 — the rank's own previous-timestep rows.
         Returns (context_width, P) rows ordered to match ``local_mats``.
         """
-        if self.mode == "allgather":
-            return jax.lax.all_gather(payload, self.axis, tiled=True)
-        if self.mode == "onesided":
-            # stateless fallback (one-shot put + immediate wait); the
-            # executing backends carry (recv, sig) across steps instead
-            recv, sig = self.onesided_state(payload.shape[-1], payload.dtype)
-            recv, sig = self.onesided_push(payload, recv, sig)
-            return self.onesided_wait(recv, sig, 1, payload)
-        if self.mode == "a2a":
-            if self.a2a_cap == 0:
-                return payload  # no remote deps: context is the local block
-            rank = jax.lax.axis_index(self.axis)
-            idx = jnp.take(jnp.asarray(self.a2a_send_idx), rank, axis=0)
-            send = jnp.take(payload, idx, axis=0)      # (ndev, cap, P)
-            recv = jax.lax.all_to_all(send, self.axis, 0, 0)
-            return jnp.concatenate(
-                [recv.reshape(self.ndev * self.a2a_cap, -1), payload])
-        if self.halo == 0:
-            return payload
-        h, P = self.halo, payload.shape[-1]
-        zeros = jnp.zeros((h, P), payload.dtype)
-        fwd = [(r, r + 1) for r in range(self.ndev - 1)]
-        from_left = (jax.lax.ppermute(payload[-h:], self.axis, fwd)
-                     if fwd else zeros)
-        if self.mode == "ring":
-            return jnp.concatenate([from_left, payload])
-        bwd = [(r, r - 1) for r in range(1, self.ndev)]
-        from_right = (jax.lax.ppermute(payload[:h], self.axis, bwd)
-                      if bwd else zeros)
-        return jnp.concatenate([from_left, payload, from_right])
+        with jax.named_scope("exchange"):
+            if self.mode == "allgather":
+                return jax.lax.all_gather(payload, self.axis, tiled=True)
+            if self.mode == "onesided":
+                # stateless fallback (one-shot put + immediate wait); the
+                # executing backends carry (recv, sig) across steps instead
+                recv, sig = self.onesided_state(payload.shape[-1],
+                                                payload.dtype)
+                recv, sig = self.onesided_push(payload, recv, sig)
+                return self.onesided_wait(recv, sig, 1, payload)
+            if self.mode == "a2a":
+                if self.a2a_cap == 0:
+                    # no remote deps: the context is the local block
+                    return payload
+                rank = jax.lax.axis_index(self.axis)
+                idx = jnp.take(jnp.asarray(self.a2a_send_idx), rank, axis=0)
+                send = jnp.take(payload, idx, axis=0)      # (ndev, cap, P)
+                recv = jax.lax.all_to_all(send, self.axis, 0, 0)
+                return jnp.concatenate(
+                    [recv.reshape(self.ndev * self.a2a_cap, -1), payload])
+            if self.halo == 0:
+                return payload
+            h, P = self.halo, payload.shape[-1]
+            zeros = jnp.zeros((h, P), payload.dtype)
+            fwd = [(r, r + 1) for r in range(self.ndev - 1)]
+            from_left = (jax.lax.ppermute(payload[-h:], self.axis, fwd)
+                         if fwd else zeros)
+            if self.mode == "ring":
+                return jnp.concatenate([from_left, payload])
+            bwd = [(r, r - 1) for r in range(1, self.ndev)]
+            from_right = (jax.lax.ppermute(payload[:h], self.axis, bwd)
+                          if bwd else zeros)
+            return jnp.concatenate([from_left, payload, from_right])
 
     def trim(self, gathered):
         """Drop dead padding columns from a (padded_width, ...) output."""
@@ -237,22 +240,24 @@ class CommPlan:
         the producer, not inferred by the consumer.  Slot writes use
         ``.at[...].set(mode="drop")`` like the token-dispatch path.
         """
-        if self.a2a_cap == 0:
+        with jax.named_scope("exchange"):
+            if self.a2a_cap == 0:
+                return recv, sig
+            rank = jax.lax.axis_index(self.axis)
+            P = payload.shape[-1]
+            for off, idx_tab, flag_tab in self._onesided_offsets:
+                idx = jnp.take(jnp.asarray(idx_tab), rank, axis=0)   # (cap,)
+                block = jnp.take(payload, idx, axis=0)               # (cap, P)
+                flag = jnp.take(jnp.asarray(flag_tab), rank)
+                packet = jnp.concatenate(
+                    [block, jnp.full((1, P), flag, block.dtype)])
+                perm = [(r, (r + off) % self.ndev) for r in range(self.ndev)]
+                got = jax.lax.ppermute(packet, self.axis, perm)
+                src = jax.lax.rem(rank - off + self.ndev, self.ndev)
+                recv = recv.at[src].set(got[:-1], mode="drop")
+                sig = sig.at[src].add(got[-1, 0].astype(jnp.int32),
+                                      mode="drop")
             return recv, sig
-        rank = jax.lax.axis_index(self.axis)
-        P = payload.shape[-1]
-        for off, idx_tab, flag_tab in self._onesided_offsets:
-            idx = jnp.take(jnp.asarray(idx_tab), rank, axis=0)   # (cap,)
-            block = jnp.take(payload, idx, axis=0)               # (cap, P)
-            flag = jnp.take(jnp.asarray(flag_tab), rank)
-            packet = jnp.concatenate(
-                [block, jnp.full((1, P), flag, block.dtype)])
-            perm = [(r, (r + off) % self.ndev) for r in range(self.ndev)]
-            got = jax.lax.ppermute(packet, self.axis, perm)
-            src = jax.lax.rem(rank - off + self.ndev, self.ndev)
-            recv = recv.at[src].set(got[:-1], mode="drop")
-            sig = sig.at[src].add(got[-1, 0].astype(jnp.int32), mode="drop")
-        return recv, sig
 
     def onesided_wait(self, recv, sig, t, payload):
         """The consumer side: ``signal_wait_until`` + context assembly.
@@ -262,12 +267,13 @@ class CommPlan:
         mode bit-exact with blocking: dead pairs and the t=0 epoch are
         masked instead of synchronized away.
         """
-        if self.a2a_cap == 0:
-            return payload
-        ready = sig >= jnp.asarray(t).astype(sig.dtype)
-        slots = jnp.where(ready[:, None, None], recv, jnp.zeros_like(recv))
-        return jnp.concatenate(
-            [slots.reshape(self.ndev * self.a2a_cap, -1), payload])
+        with jax.named_scope("exchange"):
+            if self.a2a_cap == 0:
+                return payload
+            ready = sig >= jnp.asarray(t).astype(sig.dtype)
+            slots = jnp.where(ready[:, None, None], recv, jnp.zeros_like(recv))
+            return jnp.concatenate(
+                [slots.reshape(self.ndev * self.a2a_cap, -1), payload])
 
 
 def _padded_static_inputs(graph: TaskGraph, padded: int):

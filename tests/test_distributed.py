@@ -38,6 +38,35 @@ print("CSP8OK")
     assert "CSP8OK" in out
 
 
+@pytest.mark.parametrize("spec,many", [
+    ("shardmap-csp", False),
+    ("shardmap-csp[comm=onesided]", False),
+    ("shardmap-csp[comm_overlap=True]", False),
+    ("shardmap-csp", True),           # the combined prepare_many program
+    ("shardmap-pipeline", False),
+])
+def test_rank_runner_moves_no_table_between_devices(spec, many):
+    """The dependency tables are placed on the mesh when the runner is
+    prepared: a run under a disallowing transfer guard copies none of
+    them, and its outputs are the oracle's."""
+    out = run_sub(f"""
+import jax
+from repro.core import make_graph, check_outputs
+from repro.backends import get_backend
+g = make_graph(width=16, height=8, pattern="nearest", iterations=4, radix=5)
+be = get_backend({spec!r}, devices=jax.devices()[:4])
+runner = be.prepare_many([g, g]) if {many!r} else be.prepare([g])
+with jax.transfer_guard("disallow"):
+    outs = runner()
+    outs = runner()
+assert len(outs) == (2 if {many!r} else 1)
+for o in outs:
+    check_outputs(g, o)
+print("NOTRANSFEROK")
+""", devices=4)
+    assert "NOTRANSFEROK" in out
+
+
 def test_backend_conformance_8dev():
     """The conformance matrix (every backend x every pattern) on 8 ranks."""
     out = run_sub("""

@@ -95,6 +95,32 @@ def test_xla_scan_ops_carry_the_task_step_scopes(one_chip):
         assert any(f"/{scope}/" in o for o in ops.values()), scope
 
 
+def test_csp_exchange_ops_carry_the_exchange_scope(topo):
+    """At the four-chip cell's size (W=512 over 4 ranks, H=1000, radix 5)
+    the compiled rank program's collective ops, as the benchmark's trace
+    reduction finds them, name the exchange: a device trace can tell it
+    from the task step."""
+    from chipbench.trace import COLLECTIVE
+
+    mesh = Mesh(np.array(topo.devices[:4]), ("cols",))
+    g = make_graph(width=512, height=1000, pattern="nearest", kernel="compute",
+                   iterations=1, radix=5)
+    be = get_backend("shardmap-csp", mesh=mesh)
+    fn, plan = be._program_one(g)
+    assert plan.mode == "halo"
+    on = lambda a, spec: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(mesh, spec))
+    mats_spec, iters_spec = be._table_specs()
+    text = fn.lower(on(plan.local_mats, mats_spec),
+                    on(plan.iters, iters_spec)).compile().as_text()
+    ops = re.findall(r'%([^\s=]+) = [^\n]*?op_name="([^"]*)"', text)
+    collectives = [(n, o) for n, o in ops if COLLECTIVE.match(n)]
+    assert {n.split(".")[0] for n, _ in collectives} == {
+        "collective-permute-start", "collective-permute-done"}
+    for n, o in collectives:
+        assert "/exchange/" in o, (n, o)
+
+
 def test_flash_attention_compiles(one_chip):
     from repro.kernels.flash_attention import flash_attention
 
