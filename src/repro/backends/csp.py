@@ -15,19 +15,18 @@ subclasses it and picks an axis + mode preference.
 Like MPI CSP, communication and computation strictly alternate by
 default — no overlap, no task parallelism — which is exactly why the
 paper finds MPI loses its advantage under imbalance and heavy
-communication (§V-F/G).  ``comm_overlap=True`` switches both the
-single-graph and the combined multi-graph programs to the
-double-buffered form (the MPI_Isend/Irecv analogue): the scan carry
+communication (§V-F/G).  ``comm_overlap=True`` switches the program to
+the double-buffered form (the MPI_Isend/Irecv analogue): the loop carry
 holds the *pre-exchanged* context for the current timestep, and each
 step issues the next timestep's exchange immediately after producing its
 payload — ahead of the next kernel body — so XLA's async collectives may
-run while compute proceeds.  The final timestep runs outside the scan
+run while compute proceeds.  The final timestep runs after the loop
 (its payload needs no exchange), so both forms issue exactly H
 exchanges, and the exchanged values are identical — conformance is
 bit-exact either way.
 
 ``comm="onesided"`` drops the rendezvous entirely (the NVSHMEM-style
-put/signal idiom): the scan carry holds the plan's receive buffers and
+put/signal idiom): the loop carry holds the plan's receive buffers and
 signal counters (``CommPlan.onesided_state``), each step's producers
 push their dependency rows and raise the consumer's flag
 (``onesided_push``), and consumers assemble their context through the
@@ -35,6 +34,12 @@ masked ``signal_wait_until`` (``onesided_wait``) instead of joining a
 collective.  Composes with ``comm_overlap`` (the wait for step t+1 is
 issued right after step t's push, ahead of the next kernel body) and
 with the combined multi-graph program; bit-exact with every other mode.
+
+Every form runs one loop, xla-scan's ``scanvec.timestep_loop``: a trip
+runs a block of ``scanvec.BLOCK`` timesteps, each with its exchange and
+its kernel, so the loop's control and the slice of the tables are paid
+once a block.  The tables are split into those blocks once, on the host,
+and placed on the mesh when the runner is prepared.
 """
 from __future__ import annotations
 
@@ -49,7 +54,7 @@ from ..compat import pcast, shard_map
 from ..core.graph import TaskGraph
 from ..dist import collectives as CC
 from ..launch.mesh import make_mesh, rank_mesh
-from . import body
+from . import body, scanvec
 from .base import Backend, Runner, in_turn, register_backend
 
 AXIS = "cols"
@@ -65,6 +70,8 @@ class PlannedSPMDBackend(Backend):
 
     axis = AXIS
     prefer_ring = False
+
+    loop_shape = staticmethod(scanvec.loop_shape)
 
     def __init__(self, mesh: Mesh | None = None, comm: str = "auto",
                  comm_overlap: bool = False):
@@ -88,267 +95,150 @@ class PlannedSPMDBackend(Backend):
                             comm_overlap=self.comm_overlap)
 
     def prepare(self, graphs: Sequence[TaskGraph]):
-        return in_turn([self._prepare_one(g) for g in graphs])
+        return in_turn([self._runner([g]) for g in graphs])
 
     def _table_specs(self):
-        """How a plan's (local_mats, iters) lie on the mesh: each rank
-        holds its own columns of every timestep."""
-        return P(None, self.axis, None), P(None, self.axis)
+        """How a plan's split tables lie on the mesh, as ``_tables`` gives
+        them: each rank holds its own columns of every timestep, and every
+        rank the timestep numbers."""
+        a = self.axis
+        return ((P(), P(None, None, a, None), P(None, None, a)),
+                (P(), P(None, a, None), P(None, a)))
+
+    def _tables(self, plan: CC.CommPlan):
+        """``plan``'s (timesteps, local_mats, iters), split on the host into
+        the rank program's loop blocks and the rows after them.  The
+        double-buffered form's loop stops a timestep short: its last step
+        runs after the loop."""
+        return scanvec.blocked_tables(len(plan.iters) - plan.comm_overlap,
+                                      plan.local_mats, plan.iters)
 
     def _resident(self, plan: CC.CommPlan):
-        """``plan``'s tables placed on the mesh once, in the shardings the
-        rank program takes them in, so that a run moves no table."""
-        mats_spec, iters_spec = self._table_specs()
-        return (jax.device_put(plan.local_mats,
-                               NamedSharding(self.mesh, mats_spec)),
-                jax.device_put(plan.iters,
-                               NamedSharding(self.mesh, iters_spec)))
+        """``plan``'s split tables placed on the mesh once, in the shardings
+        the rank program takes them in, so that a run moves no table."""
+        return jax.tree.map(
+            lambda a, spec: jax.device_put(a, NamedSharding(self.mesh, spec)),
+            self._tables(plan), self._table_specs())
 
-    def _program_one(self, graph: TaskGraph):
-        """The jitted rank program of ``graph`` and its plan; it takes the
-        plan's (local_mats, iters) in ``_table_specs``."""
-        plan = self.plan(graph)
-        local, Pels = plan.local, graph.payload_elems
-        dynamic = local == 1  # true per-rank loops can stop early
+    def _rank_step(self, graph: TaskGraph, plan: CC.CommPlan):
+        """``graph``'s part of the rank program (inside ``shard_map``): the
+        carry before the first timestep, whose first entry holds the
+        payload rows, the step ``(carry, (t, mat, iters)) -> carry``, and
+        the task step ``(ctx, t, mat, iters) -> payload`` the
+        double-buffered forms end with."""
+        cols = plan.local_cols()
+        dynamic = plan.local == 1  # true per-rank loops can stop early
 
-        def rank_program(lmats_l, iters_l):
-            """Runs on one rank: lmats_l (H, local, ctx), iters_l (H, local)."""
-            cols = plan.local_cols()
-            payload0 = jnp.zeros((local, Pels), jnp.float32)
-            # the carry becomes device-varying after the first exchange;
-            # mark it so from the start (shard_map vma typing)
-            payload0 = pcast(payload0, (self.axis,), to="varying")
-            ts = jnp.arange(graph.height, dtype=jnp.uint32)
+        def run(ctx, t, mat, it):
+            return body.timestep(graph, t, ctx, mat, it, cols=cols,
+                                 dynamic=dynamic)
 
-            if plan.mode == "onesided":
-                recv0, sig0 = plan.onesided_state(Pels)
-                recv0 = pcast(recv0, (self.axis,), to="varying")
-                sig0 = pcast(sig0, (self.axis,), to="varying")
-
-                if plan.comm_overlap:
-                    # put/signal double buffering: step t pushes its
-                    # payload, then immediately issues step t+1's masked
-                    # wait — ahead of the next kernel body
-                    def step(carry, xs):
-                        ctx, recv, sig = carry
-                        t, mat_t, it_t = xs
-                        new = body.timestep(graph, t, ctx, mat_t, it_t,
-                                            cols=cols, dynamic=dynamic)
-                        recv, sig = plan.onesided_push(new, recv, sig)
-                        ctx = plan.onesided_wait(recv, sig, t + 1, new)
-                        return (ctx, recv, sig), None
-
-                    ctx0 = plan.onesided_wait(recv0, sig0, 0, payload0)
-                    (ctx, _, _), _ = jax.lax.scan(
-                        step, (ctx0, recv0, sig0),
-                        (ts[:-1], lmats_l[:-1], iters_l[:-1]))
-                    return body.timestep(graph, ts[-1], ctx, lmats_l[-1],
-                                         iters_l[-1], cols=cols,
-                                         dynamic=dynamic)
-
-                def step(carry, xs):
-                    payload, recv, sig = carry
-                    t, mat_t, it_t = xs
-                    ctx = plan.onesided_wait(recv, sig, t, payload)
-                    new = body.timestep(graph, t, ctx, mat_t, it_t,
-                                        cols=cols, dynamic=dynamic)
-                    recv, sig = plan.onesided_push(new, recv, sig)
-                    return (new, recv, sig), None
-
-                (final, _, _), _ = jax.lax.scan(
-                    step, (payload0, recv0, sig0), (ts, lmats_l, iters_l))
-                return final
-
+        # the carry becomes device-varying after the first exchange; mark
+        # it so from the start (shard_map vma typing)
+        varying = lambda a: pcast(a, (self.axis,), to="varying")
+        payload0 = varying(jnp.zeros((plan.local, graph.payload_elems),
+                                     jnp.float32))
+        if plan.mode == "onesided":
+            recv0, sig0 = map(varying,
+                              plan.onesided_state(graph.payload_elems))
             if plan.comm_overlap:
-                # double-buffered: the carry holds this step's already-
-                # exchanged context; each step issues the *next* step's
-                # exchange ahead of the next kernel body.  The last
-                # timestep runs outside the scan — its payload needs no
-                # further exchange, so the program issues exactly H
-                # exchanges, the same count as the blocking form
-                def step(ctx_payload, xs):
-                    t, mat_t, it_t = xs
-                    new = body.timestep(graph, t, ctx_payload, mat_t, it_t,
-                                        cols=cols, dynamic=dynamic)
-                    return plan.exchange(new), None
+                # put/signal double buffering: step t pushes its payload,
+                # then at once issues step t+1's masked wait, ahead of the
+                # next kernel body
+                def step(carry, row):
+                    ctx, recv, sig = carry
+                    new = run(ctx, *row)
+                    recv, sig = plan.onesided_push(new, recv, sig)
+                    ctx = plan.onesided_wait(recv, sig, row[0] + 1, new)
+                    return ctx, recv, sig
 
-                ctx, _ = jax.lax.scan(
-                    step, plan.exchange(payload0),
-                    (ts[:-1], lmats_l[:-1], iters_l[:-1]))
-                return body.timestep(graph, ts[-1], ctx, lmats_l[-1],
-                                     iters_l[-1], cols=cols, dynamic=dynamic)
+                return ((plan.onesided_wait(recv0, sig0, 0, payload0),
+                         recv0, sig0), step, run)
 
-            def step(payload, xs):
-                t, mat_t, it_t = xs
-                ctx_payload = plan.exchange(payload)
-                new = body.timestep(graph, t, ctx_payload, mat_t, it_t,
-                                    cols=cols, dynamic=dynamic)
-                return new, None
+            def step(carry, row):
+                payload, recv, sig = carry
+                new = run(plan.onesided_wait(recv, sig, row[0], payload), *row)
+                return (new,) + plan.onesided_push(new, recv, sig)
 
-            final, _ = jax.lax.scan(step, payload0, (ts, lmats_l, iters_l))
-            return final
+            return (payload0, recv0, sig0), step, run
 
-        shmapped = shard_map(
-            rank_program,
-            mesh=self.mesh,
-            in_specs=self._table_specs(),
-            out_specs=P(self.axis, None),
-        )
-        return jax.jit(shmapped), plan
+        if plan.comm_overlap:
+            # double-buffered: the carry holds this step's already-exchanged
+            # context, and each step issues the next step's exchange ahead
+            # of the next kernel body
+            return ((plan.exchange(payload0),),
+                    lambda carry, row: (plan.exchange(run(carry[0], *row)),),
+                    run)
+        return ((payload0,),
+                lambda carry, row: (run(plan.exchange(carry[0]), *row),), run)
 
-    def _compile_one(self, graph: TaskGraph):
-        fn, plan = self._program_one(graph)
-        lmats_j, iters_j = self._resident(plan)
-        compiled = fn.lower(lmats_j, iters_j).compile()
-        return compiled, plan, lmats_j, iters_j
+    def _program(self, graphs: Sequence[TaskGraph]):
+        """The jitted rank program of ``graphs`` and their plans: each step
+        of its loop exchanges and executes one timestep of every graph, so
+        XLA may overlap one graph's exchange with another's kernels (the
+        rank-parallel form of task parallelism).  It takes each plan's
+        ``_tables`` in ``_table_specs``.
 
-    def _prepare_one(self, graph: TaskGraph) -> Runner:
-        compiled, plan, lmats_j, iters_j = self._compile_one(graph)
-        return Runner(lambda: compiled(lmats_j, iters_j),
-                      lambda out: [plan.trim(np.asarray(out))])
-
-    def _compile_combined(self, graphs: Sequence[TaskGraph]):
-        """One shard_map program interleaving every graph's wavefront.
-
-        Each scan step exchanges and executes timestep ``t`` of *all*
-        graphs, so XLA may overlap one graph's ppermute/all_gather with
-        another's kernels — the rank-parallel form of task parallelism.
-        Requires a common height (the shared clock); None otherwise.
-        """
-        if len(graphs) < 2 or len({g.height for g in graphs}) != 1:
-            return None
+        The loop is ``scanvec.timestep_loop``: a block of K timesteps a
+        trip.  The double-buffered forms issue timestep 0's exchange before
+        the loop and run their last timestep after it, as its payload needs
+        no exchange, so every form issues exactly H exchanges."""
         plans = [self.plan(g) for g in graphs]
-        height = graphs[0].height
-        dynamics = [p.local == 1 for p in plans]
-        placed = [self._resident(p) for p in plans]
-        lmats = tuple(m for m, _ in placed)
-        iters = tuple(i for _, i in placed)
 
-        def rank_program(lmats_l, iters_l):
-            colss = tuple(p.local_cols() for p in plans)
-            payloads = tuple(
-                pcast(jnp.zeros((p.local, g.payload_elems), jnp.float32),
-                      (self.axis,), to="varying")
-                for p, g in zip(plans, graphs))
-            ts = jnp.arange(height, dtype=jnp.uint32)
+        def rank_program(tables):
+            parts = [self._rank_step(g, p) for g, p in zip(graphs, plans)]
 
-            if self.comm == "onesided":
-                # every graph's (recv, sig) rides the shared carry; each
-                # step pushes/waits all graphs, so one graph's puts may
-                # overlap another's kernels like the collective forms
-                states = tuple(
-                    tuple(pcast(s, (self.axis,), to="varying")
-                          for s in p.onesided_state(g.payload_elems))
-                    for p, g in zip(plans, graphs))
+            def step(carry, *rows):
+                return tuple(s(c, row) for (_, s, _), c, row
+                             in zip(parts, carry, rows))
 
-                if self.comm_overlap:
-                    def step(carry, xs):
-                        t, mats_t, its_t = xs
-                        out = []
-                        for g, p, (ctx, recv, sig), m, it, cols, dyn in zip(
-                                graphs, plans, carry, mats_t, its_t,
-                                colss, dynamics):
-                            new = body.timestep(g, t, ctx, m, it,
-                                                cols=cols, dynamic=dyn)
-                            recv, sig = p.onesided_push(new, recv, sig)
-                            ctx = p.onesided_wait(recv, sig, t + 1, new)
-                            out.append((ctx, recv, sig))
-                        return tuple(out), None
-
-                    init = tuple(
-                        (p.onesided_wait(recv, sig, 0, c), recv, sig)
-                        for p, c, (recv, sig) in zip(plans, payloads, states))
-                    carry, _ = jax.lax.scan(
-                        step, init,
-                        (ts[:-1], tuple(m[:-1] for m in lmats_l),
-                         tuple(i[:-1] for i in iters_l)))
-                    return tuple(
-                        body.timestep(g, ts[-1], ctx, m[-1], it[-1],
-                                      cols=cols, dynamic=dyn)
-                        for g, (ctx, _, _), m, it, cols, dyn in zip(
-                            graphs, carry, lmats_l, iters_l, colss,
-                            dynamics))
-
-                def step(carry, xs):
-                    t, mats_t, its_t = xs
-                    out = []
-                    for g, p, (payload, recv, sig), m, it, cols, dyn in zip(
-                            graphs, plans, carry, mats_t, its_t,
-                            colss, dynamics):
-                        ctx = p.onesided_wait(recv, sig, t, payload)
-                        new = body.timestep(g, t, ctx, m, it,
-                                            cols=cols, dynamic=dyn)
-                        recv, sig = p.onesided_push(new, recv, sig)
-                        out.append((new, recv, sig))
-                    return tuple(out), None
-
-                init = tuple((c,) + s for c, s in zip(payloads, states))
-                carry, _ = jax.lax.scan(step, init, (ts, lmats_l, iters_l))
-                return tuple(payload for payload, _, _ in carry)
-
+            blocks = tuple(b for b, _ in tables)
+            tail = tuple(t for _, t in tables)
             if self.comm_overlap:
-                # as in _compile_one: the last tick runs outside the scan
-                # so every pipeline issues exactly H exchanges
-                def step(ctxs, xs):
-                    t, mats_t, its_t = xs
-                    new = tuple(
-                        body.timestep(g, t, ctx, m, it,
-                                      cols=cols, dynamic=dyn)
-                        for g, ctx, m, it, cols, dyn in zip(
-                            graphs, ctxs, mats_t, its_t, colss, dynamics))
-                    return tuple(p.exchange(n)
-                                 for p, n in zip(plans, new)), None
+                last = jax.tree.map(lambda a: a[-1], tail)
+                tail = jax.tree.map(lambda a: a[:-1], tail)
+            carry = scanvec.timestep_loop(
+                step, tuple(init for init, _, _ in parts), blocks, tail)
+            if self.comm_overlap:
+                return tuple(run(c[0], *row) for (_, _, run), c, row
+                             in zip(parts, carry, last))
+            return tuple(c[0] for c in carry)
 
-                ctxs, _ = jax.lax.scan(
-                    step,
-                    tuple(p.exchange(c) for p, c in zip(plans, payloads)),
-                    (ts[:-1], tuple(m[:-1] for m in lmats_l),
-                     tuple(i[:-1] for i in iters_l)))
-                return tuple(
-                    body.timestep(g, ts[-1], ctx, m[-1], it[-1],
-                                  cols=cols, dynamic=dyn)
-                    for g, ctx, m, it, cols, dyn in zip(
-                        graphs, ctxs, lmats_l, iters_l, colss, dynamics))
-
-            def step(carry, xs):
-                t, mats_t, its_t = xs
-                new = tuple(
-                    body.timestep(g, t, p.exchange(c), m, it,
-                                  cols=cols, dynamic=dyn)
-                    for g, p, c, m, it, cols, dyn in zip(
-                        graphs, plans, carry, mats_t, its_t, colss, dynamics))
-                return new, None
-
-            final, _ = jax.lax.scan(step, payloads, (ts, lmats_l, iters_l))
-            return final
-
-        mats_spec, iters_spec = self._table_specs()
         shmapped = shard_map(
             rank_program,
             mesh=self.mesh,
-            in_specs=(tuple(mats_spec for _ in plans),
-                      tuple(iters_spec for _ in plans)),
+            in_specs=(tuple(self._table_specs() for _ in plans),),
             out_specs=tuple(P(self.axis, None) for _ in plans),
         )
-        compiled = jax.jit(shmapped).lower(lmats, iters).compile()
-        return compiled, plans, lmats, iters
+        return jax.jit(shmapped), plans
 
-    def prepare_many(self, graphs: Sequence[TaskGraph]):
-        graphs = list(graphs)
-        built = self._compile_combined(graphs)
-        if built is None:
-            return self.prepare(graphs)
-        compiled, plans, lmats, iters = built
-        return Runner(lambda: compiled(lmats, iters),
+    def _compile(self, graphs: Sequence[TaskGraph]):
+        fn, plans = self._program(graphs)
+        tables = tuple(self._resident(p) for p in plans)
+        return fn.lower(tables).compile(), plans, tables
+
+    def _runner(self, graphs: Sequence[TaskGraph]) -> Runner:
+        compiled, plans, tables = self._compile(graphs)
+        return Runner(lambda: compiled(tables),
                       lambda outs: [p.trim(np.asarray(o))
                                     for p, o in zip(plans, outs)])
 
+    @staticmethod
+    def _lockstep(graphs: Sequence[TaskGraph]) -> bool:
+        """Can ``graphs`` share one rank program?  They need a common
+        height, the shared clock; one graph gains nothing from it."""
+        return len(graphs) > 1 and len({g.height for g in graphs}) == 1
+
+    def prepare_many(self, graphs: Sequence[TaskGraph]):
+        graphs = list(graphs)
+        if not self._lockstep(graphs):
+            return self.prepare(graphs)
+        return self._runner(graphs)
+
     def lowered_hlo(self, graphs: Sequence[TaskGraph]) -> List[str]:
         graphs = list(graphs)
-        built = self._compile_combined(graphs)
-        if built is not None:
-            return [built[0].as_text()]
-        return [self._compile_one(g)[0].as_text() for g in graphs]
+        groups = [graphs] if self._lockstep(graphs) else [[g] for g in graphs]
+        return [self._compile(gs)[0].as_text() for gs in groups]
 
 
 @register_backend("shardmap-csp")

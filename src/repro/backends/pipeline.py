@@ -15,7 +15,7 @@ sweeps: graphs whose deps also reach right fall back to the plan's
 ``allgather`` — so the backend joins the full benchmark matrix
 (every pattern x every backend) unmodified.  Multi-graph scenarios
 (``run_many``) inherit ``PlannedSPMDBackend``'s combined program: every
-pipeline advances one clock tick per scan step, rings interleaved.
+pipeline advances one clock tick per loop step, rings interleaved.
 
 ``comm_overlap=True`` (inherited from ``PlannedSPMDBackend``) switches
 to the double-buffered program: the activation ring transfer for clock

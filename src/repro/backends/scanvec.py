@@ -29,42 +29,44 @@ BLOCK = 8
 
 def loop_shape(height: int) -> Tuple[int, int]:
     """(trips, steps per trip) of the timestep loop over ``height`` steps;
-    the ``height - trips * steps`` steps left over follow the loop."""
-    steps = min(BLOCK, height)
+    the ``height - trips * steps`` steps left over follow the loop.  A
+    loop over no steps makes no trip."""
+    steps = max(1, min(BLOCK, height))
     return height // steps, steps
 
 
-def blocked_tables(height: int, *tables: np.ndarray):
-    """The timestep numbers and each ``(H, ...)`` table, split once into
-    the loop's ``(trips, steps, ...)`` blocks and the ``(H % steps, ...)``
-    tail that follows the loop: ``((ts, *tables), (ts, *tables))``."""
-    trips, steps = loop_shape(height)
-    n = trips * steps
-    tables = (np.arange(height, dtype=np.uint32),) + tables
-    blocks = tuple(jnp.asarray(a[:n].reshape((trips, steps) + a.shape[1:]))
-                   for a in tables)
-    tail = tuple(jnp.asarray(a[n:]) for a in tables)
-    return blocks, tail
+def blocked_tables(steps: int, *tables: np.ndarray):
+    """The timestep numbers and each ``(H, ...)`` table, split once on the
+    host into the loop's ``(trips, K, ...)`` blocks over the first
+    ``steps`` timesteps and the rows after them, which follow the loop:
+    ``((ts, *tables), (ts, *tables))`` of numpy arrays."""
+    trips, k = loop_shape(steps)
+    n = trips * k
+    tables = (np.arange(len(tables[0]), dtype=np.uint32),) + tables
+    return (tuple(a[:n].reshape((trips, k) + a.shape[1:]) for a in tables),
+            tuple(a[n:] for a in tables))
 
 
 def timestep_loop(step: Callable, init, blocks, tail):
-    """Run ``step(payload, t, mat, iters) -> payload`` over every timestep
-    of ``blocked_tables``' output: one loop trip a block, the tail after.
+    """Run ``step(payload, *row) -> payload`` over every timestep of
+    ``blocks`` and ``tail``, pytrees of tables split as
+    ``blocked_tables`` splits them, ``row`` each table's row at the step:
+    one loop trip a block, the tail after.
 
     Each step's payload passes an optimization barrier before the next
     step reads it: the next combine reads one column of it, and without
     the barrier XLA would drop the kernel of every step but a block's
     last, whose result only the loop carry keeps."""
 
-    def run(payload, ts, mats, iters):
-        for j in range(ts.shape[0]):
+    def run(payload, tables):
+        for j in range(jax.tree.leaves(tables)[0].shape[0]):
             payload = jax.lax.optimization_barrier(
-                step(payload, ts[j], mats[j], iters[j]))
+                step(payload, *jax.tree.map(lambda a: a[j], tables)))
         return payload
 
-    payload, _ = jax.lax.scan(lambda p, xs: (run(p, *xs), None), init,
+    payload, _ = jax.lax.scan(lambda p, xs: (run(p, xs), None), init,
                               blocks)
-    return run(payload, *tail)
+    return run(payload, tail)
 
 
 @register_backend("xla-scan")
@@ -76,8 +78,8 @@ class ScanBackend(StackedProgramBackend):
     def _build(self, graphs: Sequence[TaskGraph]):
         """One program looping over each graph in turn (independent
         execution)."""
-        tables = [blocked_tables(g.height, *body.graph_static_inputs(g))
-                  for g in graphs]
+        tables = [jax.tree.map(jnp.asarray, blocked_tables(
+            g.height, *body.graph_static_inputs(g))) for g in graphs]
 
         def program(all_tables):
             outs = []
@@ -100,10 +102,10 @@ class ScanBackend(StackedProgramBackend):
             return None
         g0 = graphs[0]
         mats, iters = body.stacked_static_inputs(graphs)
-        blocks, tail = blocked_tables(
+        blocks, tail = jax.tree.map(jnp.asarray, blocked_tables(
             g0.height,
             mats.transpose(1, 0, 2, 3),  # (H, G, W, W)
-            iters.transpose(1, 0, 2))    # (H, G, W)
+            iters.transpose(1, 0, 2)))   # (H, G, W)
 
         def program(blocks, tail):
             init = jnp.zeros((len(graphs), g0.width, g0.payload_elems),
